@@ -24,10 +24,8 @@ pages before the call (``nn.attention.apply_paged_prefill`` does the
 scatter) — the kernel then reads old prefix and fresh chunk uniformly
 through the table, so no per-request scratch cache round-trip exists.
 
-TPU-lowering notes (validated with interpret=True on CPU): the
-(n_kv, g*S) accumulator tiles assume Mosaic relayout support; pad
-head_dim/page_size/bucket to the (8, 128) fp32 tile for production
-shapes.
+Compiles for TPU v5e at qwen3-1.7b widths (tests/test_tpu_compile.py);
+off-TPU it runs in interpret mode.
 """
 from __future__ import annotations
 
@@ -38,17 +36,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+from repro.runtime import on_tpu
 
 _NEG = -1e30
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
 
 
 def _prefill_kernel(table_ref, start_ref, count_ref, win_ref, q_ref,
@@ -80,7 +70,9 @@ def _prefill_kernel(table_ref, start_ref, count_ref, win_ref, q_ref,
     kv_pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, 1, ps), 2)
     valid = (kv_pos <= q_pos) & (kv_pos < start + count) & (slot < count)
     win = win_ref[0]
-    valid = valid & jnp.where(win > 0, q_pos - kv_pos < win, True)
+    # a boolean or, not a select: Mosaic cannot legalize arith.select
+    # over i1 vectors
+    valid = valid & ((win <= 0) | (q_pos - kv_pos < win))
     s = jnp.where(valid, s, _NEG)
 
     m_prev, l_prev = m_ref[...], l_ref[...]
@@ -129,7 +121,7 @@ def paged_prefill_attention(q, pages_k, pages_v, page_table, starts,
     g = hq // n_kv
     maxp = page_table.shape[1]
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
 
     scale = 1.0 / (d ** 0.5)
     # (B, S, n_kv, g, d) -> (B, n_kv, g*S, d): flat j = gi*S + si, so the
@@ -167,7 +159,7 @@ def paged_prefill_attention(q, pages_k, pages_v, page_table, starts,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g * s_blk, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -25,11 +25,12 @@ The backward kernels realize paper Eq. 12:
   bank tile are consecutive grid steps, which makes output-block revisiting
   with accumulate-in-place legal under TPU's sequential grid semantics.
 
-TPU-lowering notes (validated with interpret=True on CPU, per the
-assignment): the element-mode in-VMEM gather (``jnp.take``) and the
-element-mode dw segment-sum depend on Mosaic gather/scatter support; the
-block-mode kernels use only dense dots + scalar-prefetch DMAs and are the
-deployment path for very large layers (see DESIGN.md §2).
+TPU lowering: the block-mode kernels use only dense dots and
+scalar-prefetch DMAs, and compile for v5e (tests/test_tpu_compile.py).
+The element-mode in-VMEM gather (``jnp.take`` of a 1-D bucket slice by a
+2-D index tile) is refused by Mosaic, which lowers only 2-D gathers, so
+element mode runs in interpret mode only; ``ops.hashed_matmul`` raises
+rather than compile it for TPU.
 """
 from __future__ import annotations
 
@@ -43,11 +44,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import hashed, hashing
-
-# pallas renamed TPUCompilerParams -> CompilerParams across jax releases;
-# accept either so the kernels run on the pinned container jax.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 
 # ---------------------------------------------------------------------------
 # element-mode forward / transpose-forward
@@ -145,7 +141,7 @@ def element_matmul(x, w, spec: hashed.HashedSpec, *, block=(128, 128, 128),
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ci, ki: (mi, ci)),
         out_shape=jax.ShapeDtypeStruct((m, c), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -222,7 +218,7 @@ def element_dw(x, g, spec: hashed.HashedSpec, *, block=(128, 128, 128),
         out_specs=pl.BlockSpec((kp,), lambda ci, ki, mi: ((ci * bn) // panel_cols,)),
         out_shape=jax.ShapeDtypeStruct((spec.num_buckets,), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -308,7 +304,7 @@ def block_matmul(x, w, spec: hashed.HashedSpec, *, bm: int = 128,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, nc * bn), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -397,7 +393,7 @@ def block_dw(x, g, spec: hashed.HashedSpec, *, bm: int = 128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((spec.bank_tiles, brow, bcol),
                                        jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,

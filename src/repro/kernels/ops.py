@@ -6,7 +6,8 @@ kernels, and wires a custom VJP whose backward pass is *also* kernelized
 (dx = transpose-forward kernel, dw = scatter-reduce kernel; paper Eq. 12).
 
 On non-TPU backends the kernels run in interpret mode (pure-Python grid
-walk) — numerically identical, used for CI on CPU.
+walk) — numerically identical, used for CI on CPU.  Only the block-mode
+kernels compile for TPU: element mode raises there at trace time.
 """
 from __future__ import annotations
 
@@ -17,13 +18,7 @@ import jax.numpy as jnp
 
 from repro.core import hashed
 from repro.kernels import hashed_matmul as hk
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+from repro.runtime import on_tpu
 
 
 def _pad_rows(x2, bm):
@@ -115,5 +110,12 @@ def hashed_matmul(x, w, spec: hashed.HashedSpec, dtype=None,
                 f"materialize path for ragged grids")
     dtype = dtype or x.dtype
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
+    if spec.mode == "element" and not interpret:
+        raise NotImplementedError(
+            "the element-mode hashed_matmul kernel does not compile for "
+            "TPU (Mosaic lowers only 2-D gathers; the in-VMEM bucket "
+            "gather is a 1-D take with 2-D indices).  Use hash_mode="
+            "'block' with the pallas path, or hash_path='scan' / "
+            "'materialize' for element mode")
     return _hashed_matmul(x, w, spec, dtype, bool(interpret), tuple(block))

@@ -19,10 +19,8 @@ Unused table slots point at page 0 — a reserved *trash page* no live row
 owns — and are masked out through ``lengths``; rows with ``length == 0``
 (idle decode rows) produce zeros.
 
-TPU-lowering notes (validated with interpret=True on CPU, like the
-hashed-GEMM kernels): the (n_kv, ps, d) in-kernel transposes and the
-small (n_kv, g) accumulator tiles assume Mosaic's relayout support;
-pad head_dim/page_size to the (8, 128) fp32 tile for production shapes.
+Compiles for TPU v5e at qwen3-1.7b widths (tests/test_tpu_compile.py);
+off-TPU it runs in interpret mode.
 """
 from __future__ import annotations
 
@@ -33,17 +31,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+from repro.runtime import on_tpu
 
 _NEG = -1e30
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
 
 
 def _decode_kernel(table_ref, len_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
@@ -70,7 +60,9 @@ def _decode_kernel(table_ref, len_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
     valid = kv_pos < length
     win = win_ref[0]
     q_pos = length - 1
-    valid = valid & jnp.where(win > 0, q_pos - kv_pos < win, True)
+    # a boolean or, not a select: Mosaic cannot legalize arith.select
+    # over i1 vectors
+    valid = valid & ((win <= 0) | (q_pos - kv_pos < win))
     s = jnp.where(valid, s, _NEG)
 
     m_prev, l_prev = m_ref[...], l_ref[...]
@@ -116,7 +108,7 @@ def paged_decode_attention(q, pages_k, pages_v, page_table, lengths,
     g = hq // n_kv
     maxp = page_table.shape[1]
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
 
     kernel = functools.partial(_decode_kernel, ps=ps, n_kv=n_kv, g=g, d=d,
                                maxp=maxp, scale=1.0 / (d ** 0.5))
@@ -145,7 +137,7 @@ def paged_decode_attention(q, pages_k, pages_v, page_table, lengths,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

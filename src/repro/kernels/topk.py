@@ -19,11 +19,12 @@ the `jax.lax.top_k`-derived oracle tie-for-tie (ties at the boundary
 all survive, same as the oracle's value-threshold semantics).
 
 One program per row (grid ``(B,)``), the k vector rides in scalar
-prefetch, and the whole row stays in VMEM: 32 compare+reduce passes
-over (1, Vp) replace sort's O(V log V) shuffles — no data movement at
-all beyond the initial row DMA.  Validated with interpret=True on CPU
-like the other kernels; off-TPU callers get the `jax.lax.top_k`
-(full-sort) fallback instead.
+prefetch, and the whole row stays in VMEM as one ``(R, 128)`` tile (the
+row padded to a multiple of 8 * 128, so the block meets the TPU's
+8x128 tiling whatever B is): 32 compare+reduce passes over it replace
+sort's O(V log V) shuffles — no data movement at all beyond the initial
+row DMA.  Off-TPU callers get the `jax.lax.top_k` (full-sort) fallback
+instead; tests run the kernel in interpret mode.
 
 k <= 0 or k >= V disables filtering for that row (the "no top-k" case
 in SamplingParams), matching the oracle.
@@ -38,21 +39,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+from repro.runtime import on_tpu
 
 NEG = -1e30
 # numpy scalars (not jnp arrays): they inline as literals inside the
 # Pallas kernel instead of being captured as device constants
 _INT_MIN = np.int32(-(2 ** 31))
 _LOW31 = np.int32(0x7FFFFFFF)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+_LANES = 128
 
 
 def _sortable(x_f32):
@@ -69,9 +63,10 @@ def _sortable(x_f32):
 
 def _topk_kernel(k_ref, x_ref, o_ref, *, v_real, fill):
     b = pl.program_id(0)
-    x = x_ref[...].astype(jnp.float32)                  # (1, Vp)
+    x = x_ref[0].astype(jnp.float32)                    # (R, 128)
     s = _sortable(x)
-    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    col = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) * _LANES
+           + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
     valid = col < v_real
     kk = k_ref[b]
 
@@ -86,8 +81,8 @@ def _topk_kernel(k_ref, x_ref, o_ref, *, v_real, fill):
     thr = p ^ _INT_MIN                                  # k-th largest, s-space
     disabled = (kk <= 0) | (kk >= v_real)
     keep = disabled | (s >= thr)
-    o_ref[...] = jnp.where(keep & valid, x_ref[...],
-                           jnp.asarray(fill, o_ref.dtype))
+    o_ref[0] = jnp.where(keep & valid, x_ref[0],
+                         jnp.asarray(fill, o_ref.dtype))
 
 
 def _topk_mask_lax(logits, k, fill):
@@ -116,29 +111,34 @@ def topk_mask(logits, k, fill=NEG, *, use_pallas=None, interpret=None):
     b, v = logits.shape
     k = jnp.asarray(k, jnp.int32)
     if use_pallas is None:
-        use_pallas = _on_tpu()
+        use_pallas = on_tpu()
     if not use_pallas:
         return _topk_mask_lax(logits, k, fill)
     if interpret is None:
-        interpret = not _on_tpu()
-    vp = -(-v // 128) * 128
+        interpret = not on_tpu()
+    tile = 8 * _LANES
+    vp = -(-v // tile) * tile
     x = logits if vp == v else jnp.pad(
         logits, ((0, 0), (0, vp - v)),
         constant_values=jnp.asarray(fill, logits.dtype))
+    rows = vp // _LANES
+    x = x.reshape(b, rows, _LANES)
     kernel = functools.partial(_topk_kernel, v_real=v, fill=fill)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b,),
-        in_specs=[pl.BlockSpec((1, vp), lambda bi, kr: (bi, 0))],
-        out_specs=pl.BlockSpec((1, vp), lambda bi, kr: (bi, 0)),
+        in_specs=[pl.BlockSpec((1, rows, _LANES),
+                               lambda bi, kr: (bi, 0, 0))],
+        out_specs=pl.BlockSpec((1, rows, _LANES),
+                               lambda bi, kr: (bi, 0, 0)),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, vp), logits.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, rows, _LANES), logits.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
     )(k, x)
-    return out[:, :v]
+    return out.reshape(b, vp)[:, :v]

@@ -14,24 +14,30 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Arbitrary mesh for elastic-rescale restarts (e.g. (8,16) after
     losing half a pod): checkpoints restore onto any mesh (train/checkpoint
-    elastic-remesh path)."""
-    return jax.make_mesh(shape, axes)
+    elastic-remesh path).
+
+    Every axis is Auto: the sharding code relies on GSPMD propagation
+    (``with_sharding_constraint``, the bank gather), which Explicit axes
+    — ``jax.make_mesh``'s default in current JAX — reject."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def single_device_mesh():
     """1-chip mesh with the standard axis names (CI / examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_serving_mesh(tp: int, *, data: int = 1):

@@ -43,12 +43,14 @@ import repro.configs as C
 from repro import policy
 from repro.configs.reduced import reduced as reduce_cfg
 from repro.models import build
+from repro.runtime import enable_compile_cache
 from repro.serving.api import SamplingParams
 from repro.serving.engine import Engine, Request
 from repro.train import checkpoint as ckpt_lib
 
 
 def main() -> int:
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default=None, choices=C.names())
     p.add_argument("--reduced", action="store_true")

@@ -39,6 +39,7 @@ import repro.configs as C
 from repro import policy
 from repro.configs.reduced import reduced as reduce_cfg
 from repro.models import build
+from repro.runtime import enable_compile_cache
 from repro.serving.api import SamplingParams
 from repro.serving.engine import Engine, Request
 from repro.serving.http import HTTPFrontend
@@ -229,6 +230,7 @@ async def _self_test(args) -> int:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     args = _parse_args(argv)
     if args.self_test:
         return asyncio.run(_self_test(args))

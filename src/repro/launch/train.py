@@ -36,6 +36,7 @@ from repro.data import lm_stream, pipeline
 from repro.distributed import sharding as shd
 from repro.launch import mesh as mesh_lib
 from repro.models import build
+from repro.runtime import enable_compile_cache
 from repro.train import checkpoint as ckpt_lib
 from repro.train import fault_tolerance as ft
 from repro.train import grad_compress
@@ -127,9 +128,12 @@ def run(cfg, mesh, *, steps: int, batch: int, seq: int,
         export_hook = ckpt_lib.artifact_exporter(
             cfg, artifact_dir, registry_root=registry_root)
 
-    hb = ft.Heartbeat(heartbeat_path or
-                      os.path.join(ckpt_dir or "/tmp", "heartbeat.json"),
-                      host_id=jax.process_index())
+    # a heartbeat needs a home of its own: without ckpt_dir or an explicit
+    # path there is none (a shared default would collide across runs)
+    if heartbeat_path is None and ckpt_dir:
+        heartbeat_path = os.path.join(ckpt_dir, "heartbeat.json")
+    hb = ft.Heartbeat(heartbeat_path, host_id=jax.process_index()) \
+        if heartbeat_path else None
     timer = ft.StepTimer()
     it = lm_stream.batches(seed=17, batch=batch, seq_len=seq,
                            vocab=cfg.vocab_size,
@@ -148,7 +152,8 @@ def run(cfg, mesh, *, steps: int, batch: int, seq: int,
                 loss = float(np.asarray(metrics["loss"]))
                 losses.append(loss)
                 t = timer.stop()
-                hb.beat(step_i, loss=loss)
+                if hb is not None:
+                    hb.beat(step_i, loss=loss)
                 if log_every and (step_i % log_every == 0):
                     print(f"step {step_i:5d} loss {loss:.4f} "
                           f"({t['step_time']:.2f}s"
@@ -172,6 +177,7 @@ def run(cfg, mesh, *, steps: int, batch: int, seq: int,
 
 
 def main() -> int:
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True, choices=C.names())
     p.add_argument("--reduced", action="store_true",

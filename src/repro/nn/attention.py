@@ -46,11 +46,10 @@ def _serving_tp():
 
 def _shard_map(fn, mesh, in_specs, out_specs):
     """shard_map with the settings every serving dispatch needs:
-    check_rep off (pallas_call inside a shard_map cannot carry the
-    replication-checking rule set)."""
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    check_vma off (pallas_call inside a shard_map cannot carry the
+    varying-manual-axes checking rule set)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -114,6 +114,15 @@ def test_dtypes(mode, dtype):
     assert ops.hashed_matmul(x, w, spec).dtype == dtype
 
 
+def test_element_mode_refuses_compiled_path():
+    """Element mode has no TPU kernel (Mosaic lowers only 2-D gathers):
+    asking for the compiled path raises instead of falling back."""
+    spec = HashedSpec((128, 256), 0.25, mode="element", seed=1)
+    w = init(jax.random.PRNGKey(0), spec)
+    with pytest.raises(NotImplementedError, match="element-mode"):
+        ops.hashed_matmul(_mk(128, 0), w, spec, interpret=False)
+
+
 def test_row_padding():
     """Row counts that don't divide the block are padded then sliced."""
     spec = HashedSpec((128, 256), 0.25, mode="element", seed=1)

@@ -402,6 +402,7 @@ def test_parse_ratio():
 def test_bank_pspec_derives_from_active_mesh():
     from jax.sharding import PartitionSpec as P
     from repro.distributed import sharding as shd
+    from repro.launch.mesh import single_device_mesh
     from repro.nn import layers as L
     spec = HashedSpec((60, 60), 0.5, mode="element", seed=1, panel_cols=0)
     n0 = spec.real_param_shape()[0]
@@ -409,6 +410,6 @@ def test_bank_pspec_derives_from_active_mesh():
     # no mesh: production 256-grid fallback -> replicated
     assert L.bank_pspec(spec) == P(None)
     # tiny CI mesh: 1x1 grid divides everything -> sharded spec
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = single_device_mesh()
     with shd.use_mesh(mesh):
         assert L.bank_pspec(spec) == P((L.FSDP, L.TP))

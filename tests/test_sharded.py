@@ -38,7 +38,6 @@ if jax.device_count() < 2:
         "XLA_FLAGS=--xla_force_host_platform_device_count=8",
         allow_module_level=True)
 
-from jax.experimental.shard_map import shard_map  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.configs.base import ArchConfig  # noqa: E402
@@ -124,13 +123,13 @@ def test_paged_decode_shard_map_parity(impl):
     q, pk, pv, table, lengths = _rand_paged(rng)
     full = np.asarray(fn(q, pk, pv, table, lengths, 0))
     mesh = make_serving_mesh(2)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda q_, k_, v_, t_, l_, w_: fn(q_, k_, v_, t_, l_, w_),
         mesh=mesh,
         in_specs=(P(None, "model", None), P(None, None, "model", None),
                   P(None, None, "model", None), P(None, None), P(None),
                   P()),
-        out_specs=P(None, "model", None), check_rep=False)
+        out_specs=P(None, "model", None), check_vma=False)
     got = np.asarray(sharded(q, pk, pv, table, lengths, jnp.int32(0)))
     np.testing.assert_array_equal(got, full)
 
@@ -149,14 +148,14 @@ def test_paged_prefill_shard_map_parity(impl):
     counts = rng.integers(1, s + 1, size=b).astype(np.int32)
     full = np.asarray(fn(q, pk, pv, table, starts, counts, 0))
     mesh = make_serving_mesh(2)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda q_, k_, v_, t_, s_, c_, w_: fn(q_, k_, v_, t_, s_, c_, w_),
         mesh=mesh,
         in_specs=(P(None, None, "model", None),
                   P(None, None, "model", None),
                   P(None, None, "model", None),
                   P(None, None), P(None), P(None), P()),
-        out_specs=P(None, None, "model", None), check_rep=False)
+        out_specs=P(None, None, "model", None), check_vma=False)
     got = np.asarray(sharded(q, pk, pv, table, starts, counts,
                              jnp.int32(0)))
     if impl == "ref":
