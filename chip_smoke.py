@@ -12,24 +12,26 @@ One chip runs, in order:
 2. every Pallas kernel of the serving path (paged decode attention,
    ragged flash prefill, the top-k mask, block-mode hashed matmul),
    compiled for the chip, against its oracle in ``repro.kernels.ref``;
-3. a dense ``Engine`` that answers 8 seeded requests (greedy, and
-   sampled with temperature + top-k + top-p, which runs the top-k
-   kernel) with ``attn_impl="pallas"``, then the same requests with the
-   ``"ref"`` oracles, compared token by token;
-4. the same for the hashed variant at compression 1/8.
+3. a dense ``Engine`` that answers 8 seeded requests of 16 tokens
+   (greedy, and sampled with temperature + top-k + top-p, which runs the
+   top-k kernel) with ``attn_impl="pallas"``, then the same requests
+   with the ``"ref"`` oracles, compared token by token;
+4. the same for the hashed variant at compression 1/8, 4 tokens each.
 
 ``--four-chips`` runs only a tensor-parallel (model axis 4) engine
 against a single-device engine on the same greedy requests.
 
 Any failed check raises, so the run exits non-zero and the last line is
-missing.  On success the last line of stdout is one JSON object,
-``{"ok": true, "device": {"platform", "kind", "count"}}``.  Times, token
-counts and peak bytes printed on earlier lines are smoke diagnostics,
-not benchmark results.
+missing; a run still going after ``WATCHDOG_S`` dumps its stacks and
+exits non-zero.  On success the last line of stdout is one JSON object,
+``{"ok": true, "device": {"platform", "kind", "count"}}``.  Progress,
+times, token counts and peak bytes go to stderr; they are smoke
+diagnostics, not benchmark results.
 """
 from __future__ import annotations
 
 import argparse
+import faulthandler
 import json
 import math
 import os
@@ -38,6 +40,7 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
+_T0 = time.perf_counter()
 
 # bf16 tolerance for a kernel against its float32 oracle: the kernels
 # read bf16 and round their output to bf16 (relative step 2**-8), and
@@ -53,13 +56,25 @@ SEED = 0
 ROWS = 8
 PROMPT_LENS = (5, 11, 14, 7, 16, 9, 3, 12)   # two prefill buckets: 8, 16
 MAX_TOKENS = 16
+# The hashed engine's element mode rebuilds every virtual matrix with a
+# scalar gather on each forward pass (about 1.4 G bank entries over 28
+# layers), whatever the batch: its cost is per tick, not per token, so
+# the hashed phase runs fewer ticks.
+HASHED_TOKENS = 4
 MAX_LEN = 256
 PAGE = 16
 LOGPROB_K = 4
+# seconds: a run still going after this dumps every thread's stack to
+# stderr and exits non-zero, so a hang names the line it hangs on
+WATCHDOG_S = 1100
 
 
 def log(msg: str) -> None:
-    print(msg, flush=True)
+    """Progress goes to stderr, stamped with seconds since start: the
+    tail of stderr is what a stopped run leaves to read, and stdout
+    keeps only the result line."""
+    print(f"[{time.perf_counter() - _T0:7.1f} s] {msg}", file=sys.stderr,
+          flush=True)
 
 
 def _close(name, got, want, tol):
@@ -155,9 +170,10 @@ def kernel_phase(cfg, *, interpret: bool, seed: int = SEED) -> None:
                KERNEL_TOL)
 
 
-def requests(vocab: int, *, sampled: bool, uid0: int = 0):
-    """ROWS seeded requests; with ``sampled`` the odd ones sample with
-    temperature + top-k + top-p (the top-k kernel's path)."""
+def requests(vocab: int, *, sampled: bool, max_tokens: int = MAX_TOKENS):
+    """ROWS seeded requests of ``max_tokens`` each; with ``sampled`` the
+    odd ones sample with temperature + top-k + top-p (the top-k kernel's
+    path)."""
     import numpy as np
 
     from repro.serving.api import SamplingParams
@@ -169,40 +185,49 @@ def requests(vocab: int, *, sampled: bool, uid0: int = 0):
         prompt = rng.integers(2, vocab, size=plen).astype(np.int32)
         if sampled and i % 2:
             sp = SamplingParams(temperature=0.8, top_k=40, top_p=0.9,
-                                max_tokens=MAX_TOKENS, seed=SEED + i,
+                                max_tokens=max_tokens, seed=SEED + i,
                                 logprobs=LOGPROB_K)
         else:
-            sp = SamplingParams(max_tokens=MAX_TOKENS, logprobs=LOGPROB_K)
-        out.append(Request(uid=uid0 + i, prompt=prompt, sampling=sp))
+            sp = SamplingParams(max_tokens=max_tokens, logprobs=LOGPROB_K)
+        out.append(Request(uid=i, prompt=prompt, sampling=sp))
     return out
 
 
 def serve(engine, reqs):
     """Submit ``reqs``, step ``engine`` until it drains; check every
     request finished at its full length with in-vocab tokens and finite
-    logprobs.  Returns ({index: request}, seconds)."""
+    logprobs.  Returns ({index: request}, [seconds of each tick])."""
     import numpy as np
 
-    t0 = time.perf_counter()
     handles = [engine.submit(r) for r in reqs]
     assert all(handles), "engine refused a request"
-    budget = 4 * len(reqs) * (MAX_TOKENS + 2)
+    longest = max(r.sampling.max_tokens for r in reqs)
+    budget = 4 * len(reqs) * (longest + 2)
+    ticks = []
     while engine.pending():
+        t0 = time.perf_counter()
         engine.step()
-        budget -= 1
-        assert budget > 0, "engine did not drain"
-    dt = time.perf_counter() - t0
+        ticks.append(time.perf_counter() - t0)
+        assert len(ticks) < budget, "engine did not drain"
     vocab = engine.model.cfg.vocab_size
     done = {}
     for i, h in enumerate(handles):
         r = h.req
         assert r.done and r.finish_reason == "length", (r.uid,
                                                         r.finish_reason)
-        assert len(r.tokens) == MAX_TOKENS, (r.uid, len(r.tokens))
+        assert len(r.tokens) == r.sampling.max_tokens, (r.uid,
+                                                        len(r.tokens))
         assert all(0 <= t < vocab for t in r.tokens), r.uid
         assert np.isfinite(r.token_logprobs).all(), r.uid
         done[i] = r
-    return done, dt
+    return done, ticks
+
+
+def _ticks(ticks) -> str:
+    rest = sorted(ticks[1:]) or [0.0]
+    return (f"{len(ticks)} ticks in {sum(ticks):.1f} s, the first "
+            f"{ticks[0]:.1f} s (compiles included), median of the rest "
+            f"{rest[len(rest) // 2]:.3f} s")
 
 
 def compare(name, got, want, indices, *, greedy: bool) -> bool:
@@ -262,7 +287,8 @@ def build_model(cfg):
     return model, params
 
 
-def engine_phase(cfg, *, max_len: int = MAX_LEN) -> None:
+def engine_phase(cfg, *, max_len: int = MAX_LEN,
+                 max_tokens: int = MAX_TOKENS) -> None:
     """Pallas and ref engines on the same requests, compared."""
     from repro.serving.engine import Engine
 
@@ -271,18 +297,14 @@ def engine_phase(cfg, *, max_len: int = MAX_LEN) -> None:
     for impl in ("pallas", "ref"):
         eng = Engine(model, params, max_concurrency=ROWS, max_len=max_len,
                      page_size=PAGE, attn_impl=impl, eos_id=-1, seed=SEED)
-        done, cold = serve(eng, requests(cfg.vocab_size, sampled=True))
-        again, warm = serve(eng, requests(cfg.vocab_size, sampled=True,
-                                          uid0=ROWS))
-        repeat = all(again[i].tokens == done[i].tokens for i in done)
+        done, ticks = serve(eng, requests(cfg.vocab_size, sampled=True,
+                                          max_tokens=max_tokens))
         eng.shutdown()
         del eng
         outs[impl] = done
         log(f"  {cfg.name} attn_impl={impl}: "
-            f"{sum(len(r.tokens) for r in done.values())} tokens in "
-            f"{cold:.1f} s first pass (compiles included), {warm:.2f} s "
-            f"second pass; second pass repeats the tokens: {repeat}; "
-            f"peak bytes in use {_peak_bytes()}")
+            f"{sum(len(r.tokens) for r in done.values())} tokens, "
+            f"{_ticks(ticks)}; peak bytes in use {_peak_bytes()}")
     greedy = [i for i in outs["ref"] if not i % 2]
     sampled = [i for i in outs["ref"] if i % 2]
     compare(f"{cfg.name} greedy pallas vs ref", outs["pallas"],
@@ -306,12 +328,12 @@ def four_chip_phase(cfg, tp: int = 4) -> None:
         eng = Engine(model, params, max_concurrency=ROWS, max_len=MAX_LEN,
                      page_size=PAGE, attn_impl="pallas", eos_id=-1,
                      seed=SEED, mesh=mesh)
-        outs[name], dt = serve(eng, requests(cfg.vocab_size,
-                                             sampled=False))
+        outs[name], ticks = serve(eng, requests(cfg.vocab_size,
+                                                sampled=False))
         eng.shutdown()
         del eng
-        log(f"  {cfg.name} {name}: {dt:.1f} s (compiles included); "
-            f"peak bytes in use on device 0 {_peak_bytes()}")
+        log(f"  {cfg.name} {name}: {_ticks(ticks)}; peak bytes in use on "
+            f"device 0 {_peak_bytes()}")
     identical = compare(f"{cfg.name} tp={tp} vs single device",
                         outs[f"tp={tp}"], outs["single device"],
                         range(ROWS), greedy=True)
@@ -323,6 +345,7 @@ def main(argv=None) -> int:
     p.add_argument("--four-chips", action="store_true",
                    help="run only the tensor-parallel phase on 4 chips")
     args = p.parse_args(argv)
+    faulthandler.dump_traceback_later(WATCHDOG_S, exit=True)
 
     import jax
     devs = jax.devices()
@@ -347,7 +370,8 @@ def main(argv=None) -> int:
         log("phase: dense engine")
         engine_phase(cfg)
         log("phase: hashed engine (1/8)")
-        engine_phase(cfg.hashed_variant(0.125))
+        engine_phase(cfg.hashed_variant(0.125), max_tokens=HASHED_TOKENS)
+    faulthandler.cancel_dump_traceback_later()
     log(f"all phases passed in {time.perf_counter() - t0:.1f} s")
     print(json.dumps({"ok": True, "device": device}))
     return 0
