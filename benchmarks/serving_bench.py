@@ -95,6 +95,35 @@ def _workload_delta(eng, base):
     return {k: v for k, v in d.items() if k not in _GAUGES}
 
 
+def _phase_histograms(reqs) -> dict:
+    """The baseline's per-phase time summaries, read from the engine's
+    account of the steps that advanced ``reqs`` (`repro.obs.trace.Step`,
+    self seconds by phase): ``engine.decode_tick_s`` one per decoding
+    step (its decode, sample and commit phases),
+    ``engine.prefill_batch_s`` one per prefilling step and
+    ``engine.prefill_chunk_s`` one per row chunk (its prefill phase)."""
+    steps = list({id(s): s for r in reqs for s in r.steps}.values())
+
+    def summary(vals):
+        total = round(sum(vals), 9)
+        return {"count": len(vals), "sum": total,
+                "mean": round(total / len(vals), 9) if vals else 0.0}
+
+    def phase(s, *names):
+        return sum(s.phase_s.get(n, 0.0) for n in names)
+
+    prefill = [s for s in steps if s.prefill_rows]
+    return {
+        "engine.decode_tick_s": summary(
+            [phase(s, "decode", "sample", "commit")
+             for s in steps if s.decode_rows]),
+        "engine.prefill_batch_s": summary(
+            [phase(s, "prefill") for s in prefill]),
+        "engine.prefill_chunk_s": summary(
+            [phase(s, "prefill") for s in prefill
+             for _ in range(s.prefill_rows)])}
+
+
 def _configs():
     base = reduced(C.get("qwen3-1.7b")).with_(dtype="float32")
     return [("qwen3-reduced-dense", base),
@@ -138,6 +167,7 @@ def bench_level(model, params, cfg, *, concurrency: int, requests: int,
     wall = time.perf_counter() - t0
     stats = eng.stats()
     d = _workload_delta(eng, base)
+    d.update(_phase_histograms(eng._done))
     total_tokens = d["engine.tokens"]
     out = {"concurrency": concurrency, "requests": requests,
            "tokens": total_tokens,

@@ -221,6 +221,7 @@ def block_indices(spec: HashedSpec):
 # materialization (oracle / small layers)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("hashed_expand")
 def materialize(w, spec: HashedSpec, dtype=None):
     """Build the full virtual matrix V (rows, cols)."""
     spec.validate()
@@ -239,6 +240,7 @@ def materialize(w, spec: HashedSpec, dtype=None):
     return v[: spec.rows, : spec.cols].astype(dtype)
 
 
+@jax.named_scope("hashed_expand")
 def materialize_rows(w, spec: HashedSpec, row_ids, dtype=None):
     """Gather virtual rows V[row_ids, :] without building all of V.
 
@@ -316,11 +318,13 @@ def matmul_scan(x, w, spec: HashedSpec, panel_cols: int = 0, dtype=None,
     if spec.mode == "element":
         def body(carry, j0):
             def panel(w_, x_):
-                i = jnp.arange(spec.rows, dtype=jnp.int32)[:, None]
-                j = j0 + jnp.arange(panel_cols, dtype=jnp.int32)[None, :]
-                idx, sgn = element_indices(spec, i, j)
-                v = _constrain_panel(
-                    (w_[idx] * sgn.astype(w_.dtype)).astype(dtype))
+                with jax.named_scope("hashed_expand"):
+                    i = jnp.arange(spec.rows, dtype=jnp.int32)[:, None]
+                    j = j0 + jnp.arange(panel_cols,
+                                        dtype=jnp.int32)[None, :]
+                    idx, sgn = element_indices(spec, i, j)
+                    v = _constrain_panel(
+                        (w_[idx] * sgn.astype(w_.dtype)).astype(dtype))
                 return jax.lax.dot_general(
                     x_, v, (((x_.ndim - 1,), (0,)), ((), ())))
 
@@ -345,10 +349,11 @@ def matmul_scan(x, w, spec: HashedSpec, panel_cols: int = 0, dtype=None,
             idx_col, sgn_col = args                     # (gi,)
 
             def panel(w_, xt_):
-                tiles = (w_[idx_col]
-                         * sgn_col[:, None, None].astype(w_.dtype))  # (gi,bm,bn)
-                vpanel = _constrain_panel(
-                    tiles.reshape(gi * bm, bn).astype(dtype))
+                with jax.named_scope("hashed_expand"):
+                    tiles = (w_[idx_col] * sgn_col[:, None, None]
+                             .astype(w_.dtype))             # (gi,bm,bn)
+                    vpanel = _constrain_panel(
+                        tiles.reshape(gi * bm, bn).astype(dtype))
                 return jax.lax.dot_general(
                     xt_.reshape(xt_.shape[0], gi * bm), vpanel,
                     (((1,), (0,)), ((), ())))

@@ -24,10 +24,11 @@ Prints per-request generations + aggregate throughput.
 
 Observability: --metrics-out dumps the full metrics-registry snapshot
 as JSON (and prints a human-readable table on exit); --trace-out
-records per-request spans (queued / prefill chunks / decode ticks /
-preemptions / COW copies) as Chrome trace-event JSON — open the file
-at https://ui.perfetto.dev.  --debug-leak-check audits the paged KV
-cache's refcounts at shutdown.
+records the engine's steps and their phases and per-request spans
+(queued / prefill chunks / first token / preemptions / COW copies) as
+Chrome trace-event JSON on the profiler's clock — open the file at
+https://ui.perfetto.dev, beside a device trace of the same run.
+--debug-leak-check audits the paged KV cache's refcounts at shutdown.
 """
 from __future__ import annotations
 
@@ -149,8 +150,9 @@ def main() -> int:
                    help="write the metrics-registry snapshot (counters, "
                         "gauges, latency histograms) as JSON on exit")
     p.add_argument("--trace-out", default=None, metavar="FILE",
-                   help="record per-request spans and write Chrome "
-                        "trace-event JSON on exit (open in Perfetto)")
+                   help="record engine step and per-request spans and "
+                        "write Chrome trace-event JSON on exit (open in "
+                        "Perfetto)")
     p.add_argument("--debug-leak-check", action="store_true",
                    help="audit paged KV refcounts at shutdown; anomalies "
                         "export as the kv.leak_anomalies metric")

@@ -423,6 +423,7 @@ def _build_decoder(cfg: ArchConfig) -> Model:
                 [batch["image_embeds"].astype(x.dtype), x], axis=1)
         return shd.constraint(x, P(L.BATCH, None, None))
 
+    @jax.named_scope("lm_head")
     def logits_fn(params, x):
         x = norm_apply(params["final_norm"], x)
         if cfg.tie_embeddings:

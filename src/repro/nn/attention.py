@@ -203,6 +203,7 @@ def _attend_unchunked(plan: AttentionPlan, q, k, v, q_pos, kv_pos, kv_valid,
     return out.reshape(b, s, hq * d).astype(plan.dtype)
 
 
+@jax.named_scope("attn")
 def apply(plan: AttentionPlan, params, x, *, positions, cache=None,
           cache_index=None, kv_source=None, is_global=None):
     """Returns (out, new_cache).
@@ -271,6 +272,7 @@ def apply(plan: AttentionPlan, params, x, *, positions, cache=None,
     return L.linear_apply(o_lin, params["o"], out), new_cache
 
 
+@jax.named_scope("attn")
 def apply_paged(plan: AttentionPlan, params, x, *, pages, page_table,
                 lengths, is_global=None, impl: str = "ref"):
     """One decode step (S=1) through a paged KV cache.
@@ -319,8 +321,9 @@ def apply_paged(plan: AttentionPlan, params, x, *, pages, page_table,
         poff = lengths % ps
         # distinct live rows own distinct pages (allocator invariant);
         # idle rows all write the trash page, collisions harmless there
-        npk = pk.at[pidx, poff].set(k1.astype(pk.dtype))
-        npv = pv.at[pidx, poff].set(v1.astype(pv.dtype))
+        with jax.named_scope("kv_write"):
+            npk = pk.at[pidx, poff].set(k1.astype(pk.dtype))
+            npv = pv.at[pidx, poff].set(v1.astype(pv.dtype))
         return fn(q1, npk, npv, page_table, lengths + 1, window), npk, npv
 
     mesh, _tp = _serving_tp()
@@ -352,6 +355,7 @@ def apply_paged(plan: AttentionPlan, params, x, *, pages, page_table,
     return L.linear_apply(o_lin, params["o"], out), (pk, pv)
 
 
+@jax.named_scope("attn")
 def apply_paged_block(plan: AttentionPlan, params, x, *, pages, page_table,
                       lengths, counts, is_global=None, impl: str = "ref"):
     """Multi-token decode block (speculative propose/verify) through a
@@ -397,10 +401,11 @@ def apply_paged_block(plan: AttentionPlan, params, x, *, pages, page_table,
     pidx = jnp.where(valid,
                      jnp.take_along_axis(page_table, pno, axis=1), 0)
     poff = positions % ps
-    pk = pk.at[pidx.reshape(-1), poff.reshape(-1)].set(
-        k.reshape(b * s_blk, *k.shape[2:]).astype(pk.dtype))
-    pv = pv.at[pidx.reshape(-1), poff.reshape(-1)].set(
-        v.reshape(b * s_blk, *v.shape[2:]).astype(pv.dtype))
+    with jax.named_scope("kv_write"):
+        pk = pk.at[pidx.reshape(-1), poff.reshape(-1)].set(
+            k.reshape(b * s_blk, *k.shape[2:]).astype(pk.dtype))
+        pv = pv.at[pidx.reshape(-1), poff.reshape(-1)].set(
+            v.reshape(b * s_blk, *v.shape[2:]).astype(pv.dtype))
 
     if plan.sliding_window > 0:
         window = jnp.asarray(plan.sliding_window, jnp.int32)
@@ -422,6 +427,7 @@ def apply_paged_block(plan: AttentionPlan, params, x, *, pages, page_table,
     return L.linear_apply(o_lin, params["o"], out), (pk, pv)
 
 
+@jax.named_scope("attn")
 def apply_paged_prefill(plan: AttentionPlan, params, x, *, pages,
                         page_table, starts, counts, write_from,
                         is_global=None, impl: str = "ref"):
@@ -491,10 +497,11 @@ def apply_paged_prefill(plan: AttentionPlan, params, x, *, pages,
                          jnp.take_along_axis(page_table, pno, axis=1), 0)
         poff = positions_ % ps
         nb, ns = q_.shape[0], q_.shape[1]
-        npk = pk.at[pidx.reshape(-1), poff.reshape(-1)].set(
-            k_.reshape(nb * ns, *k_.shape[2:]).astype(pk.dtype))
-        npv = pv.at[pidx.reshape(-1), poff.reshape(-1)].set(
-            v_.reshape(nb * ns, *v_.shape[2:]).astype(pv.dtype))
+        with jax.named_scope("kv_write"):
+            npk = pk.at[pidx.reshape(-1), poff.reshape(-1)].set(
+                k_.reshape(nb * ns, *k_.shape[2:]).astype(pk.dtype))
+            npv = pv.at[pidx.reshape(-1), poff.reshape(-1)].set(
+                v_.reshape(nb * ns, *v_.shape[2:]).astype(pv.dtype))
         return fn(q_, npk, npv, page_table, starts, counts, window), \
             npk, npv
 

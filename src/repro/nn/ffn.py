@@ -59,6 +59,7 @@ def init(plan: FFNPlan, key):
     return params, specs
 
 
+@jax.named_scope("mlp")
 def apply(plan: FFNPlan, params, x):
     h = L.linear_apply(
         _lin(plan, plan.d_model, plan.d_ff, plan.hash_in, (L.FSDP, L.TP)),

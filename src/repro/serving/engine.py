@@ -80,7 +80,7 @@ import numpy as np
 
 from repro.models.transformer import Model
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import ENGINE_PID, REQUEST_PID, Tracer
+from repro.obs.trace import REQUEST_PID, WAIT, Step, Tracer
 from repro.serving import sampling as sampling_lib
 from repro.serving.api import (FINISH_CANCELLED, FINISH_DEADLINE,
                                FINISH_LENGTH, FINISH_STOP, FINISH_REASONS,
@@ -134,6 +134,8 @@ class Request:
     topk_logprobs: Optional[List[List[Tuple[int, float]]]] = None
     seed_used: Optional[int] = None     # effective PRNG seed (engine-drawn
     #                                     when sampling.seed is None)
+    # the engine steps that advanced it (a prefill chunk or a token)
+    steps: List[Step] = dataclasses.field(default_factory=list)
 
     def __post_init__(self):
         # Compat shim: the legacy flat knobs and the SamplingParams
@@ -304,8 +306,11 @@ class Engine:
         Observability: ``metrics`` is the registry every component
         (engine, scheduler, paged cache, fused sampler) publishes into
         (default: a fresh private one — ``Engine.stats()`` stays a thin
-        compat view over it); ``tracer`` records per-request spans for
-        Perfetto export (default: disabled, near-zero overhead).
+        compat view over it); ``tracer`` (`repro.obs.trace`) puts each
+        step and its phases on the profiler's clock, keeps each step's
+        account (``Request.steps``, ``engine.step_s``,
+        ``engine.step_host_s``) and, when enabled, records them and
+        per-request tracks for Perfetto export (default: disabled).
         ``debug_leak_check`` (or env REPRO_DEBUG_LEAK_CHECK=1) makes
         ``shutdown()`` run the paged cache's refcount audit and export
         anomalies as the ``kv.leak_anomalies`` metric.
@@ -415,14 +420,14 @@ class Engine:
                                                  keys=FINISH_REASONS)
         self._h_ttft = self.metrics.histogram("engine.ttft_s")
         self._h_qwait = self.metrics.histogram("engine.queue_wait_s")
-        self._h_tick = self.metrics.histogram("engine.decode_tick_s")
-        self._h_chunk = self.metrics.histogram("engine.prefill_chunk_s")
+        self._h_step = self.metrics.histogram("engine.step_s")
+        self._h_step_host = self.metrics.histogram("engine.step_host_s")
+        self._step_args = {"model": model_tag} if model_tag else {}
         # batched ragged prefill: dispatches = fused calls, rows/tokens =
         # work coalesced per call, fallback_chunks = rows that took the
         # sequential path (non-chunkable kinds, or batching off)
         self._pb_counts = self.metrics.group("engine.prefill_batch", keys=(
             "dispatches", "rows", "tokens", "fallback_chunks"))
-        self._h_pbatch = self.metrics.histogram("engine.prefill_batch_s")
         self._leak_anomalies = self.metrics.counter("kv.leak_anomalies")
         self.last_leak_error: Optional[str] = None
         # engine.shard.* exists only on mesh engines: non-mesh registry
@@ -453,10 +458,10 @@ class Engine:
             # pages-in/pages-out contract would copy the whole pool per
             # decode tick / prefill (backends that can't donate just
             # warn and copy — no behavior change)
-            self._decode_paged = jax.jit(
-                lambda p, t, pg, tb, ln: model.decode_paged(
-                    p, t, pg, tb, ln, attn_impl),
-                donate_argnums=(2,))
+            def decode_paged(p, t, pg, tb, ln):
+                return model.decode_paged(p, t, pg, tb, ln, attn_impl)
+
+            self._decode_paged = jax.jit(decode_paged, donate_argnums=(2,))
             self._page_copy = jax.jit(_copy_pages, donate_argnums=(0,))
             self._gather = jax.jit(_gather_prefix)
             self._cow_copy = jax.jit(_copy_page, donate_argnums=(0,))
@@ -470,10 +475,12 @@ class Engine:
             self.batched_prefill = bool(batched_prefill) \
                 and model.prefill_paged is not None
             if self.batched_prefill:
-                self._prefill_batched = jax.jit(
-                    lambda p, t, pg, tb, st, cn, wf: model.prefill_paged(
-                        p, t, pg, tb, st, cn, wf, attn_impl),
-                    donate_argnums=(2,))
+                def prefill_paged(p, t, pg, tb, st, cn, wf):
+                    return model.prefill_paged(p, t, pg, tb, st, cn, wf,
+                                               attn_impl)
+
+                self._prefill_batched = jax.jit(prefill_paged,
+                                                donate_argnums=(2,))
                 self._logits_head = jax.jit(model.logits_head)
             self.spec = None
             if draft is not None:
@@ -733,20 +740,22 @@ class Engine:
         if n_inflight > budget:
             return chunks        # budget exhausted mid-flight
         admitted: List[int] = []
-        while chunks + len(admitted) < budget:
-            free = self._free_rows()
-            if not free:
-                break
-            req = self.sched.pop_admissible(self._can_admit,
-                                            model=self.model_tag)
-            if req is None:
-                break
-            if not self._begin_prefill(free[0], req, now):
-                # can_admit is optimistic under prefix sharing (shared
-                # and reclaimable pages may overlap); put the head back
-                self.sched.unpop(req)
-                break
-            admitted.append(free[0])
+        with self.tracer.span("schedule"):
+            while chunks + len(admitted) < budget:
+                free = self._free_rows()
+                if not free:
+                    break
+                req = self.sched.pop_admissible(self._can_admit,
+                                                model=self.model_tag)
+                if req is None:
+                    break
+                if not self._begin_prefill(free[0], req, now):
+                    # can_admit is optimistic under prefix sharing
+                    # (shared and reclaimable pages may overlap); put
+                    # the head back
+                    self.sched.unpop(req)
+                    break
+                admitted.append(free[0])
         # newly admitted rows take their first chunk TOGETHER — the
         # burst-arrival case (N submissions land in one tick) coalesces
         # into one ragged dispatch instead of N single-row ones.  Dense
@@ -782,16 +791,18 @@ class Engine:
             meta = self.kv.row_meta[row]
             if meta.tail_page is not None:
                 dst = int(self.kv.table[row, meta.shared])
-                self.pages = self._cow_copy(
-                    self.pages, jnp.asarray(meta.tail_page, jnp.int32),
-                    jnp.asarray(dst, jnp.int32))
+                with self.tracer.span("cow"):
+                    self.pages = self._cow_copy(
+                        self.pages, jnp.asarray(meta.tail_page, jnp.int32),
+                        jnp.asarray(dst, jnp.int32))
                 # copy dispatched; device ordering keeps it ahead of any
                 # later pool write, so the pin can drop now
                 self.kv.drop_tail_ref(row)
         elif hit > 0:
             pids = self.kv.gather_table(row)
-            cache = self._gather(self.pages, jnp.asarray(pids),
-                                 jnp.asarray(hit, jnp.int32))
+            with self.tracer.span("prefill"):
+                cache = self._gather(self.pages, jnp.asarray(pids),
+                                     jnp.asarray(hit, jnp.int32))
             # the gather is dispatched; device ordering keeps it ahead
             # of any later pool write, so the pin can drop now
             self.kv.drop_tail_ref(row)
@@ -862,72 +873,101 @@ class Engine:
         first token and hand the row to decode."""
         st = self._prefilling[row]
         req = st.req
-        t0 = time.perf_counter()
-        tr0 = self.tracer.now()
+        tr = self.tracer
+        tr0 = tr.now()
         pos0 = st.pos
-        remaining = len(st.feed) - (st.pos if st.chunkable else 0)
-        c = remaining if (self.prefill_chunk is None or not st.chunkable) \
-            else min(self.prefill_chunk, remaining)
-        cache = st.cache if st.cache is not None else self._prefill_cache
-        if st.chunkable:
-            start, bucket, real = self._chunk_shape(st.pos, c)
-            if start != st.pos:
-                # slid-back window: rewind the write index; positions
-                # [start, pos) recompute to the same bytes
-                cache = dict(cache,
-                             index=jnp.asarray(start, jnp.int32))
-            toks = st.feed[start:start + real]
-            prompt = np.pad(toks, (0, bucket - real))
-            batch = {"tokens": jnp.asarray(prompt[None, :]),
-                     "cache": cache,
-                     "length": jnp.asarray(real, jnp.int32)}
-        else:
-            batch = {"tokens": jnp.asarray(st.feed[None, :]),
-                     "cache": cache}
-            if req.extras:
-                batch.update({k: jnp.asarray(v) for k, v in
-                              req.extras.items()})
-        logits, c1 = self._prefill(self.params, batch)
-        if self._shard_counts is not None:
-            self._shard_counts["prefill_dispatches"] += 1
-        st.cache = c1
-        new_pos = int(np.asarray(c1["index"]))
-        # land the freshly computed positions' pages; shared prefix
-        # pages (slots below first_private_page) are never rewritten —
-        # write targets outside the chunk resolve to the trash page
-        lo = max(st.pos // self.kv.page_size,
-                 self.kv.first_private_page(row))
-        hi = self.kv.pages_for(new_pos)
-        wpids = np.full((self.kv.maxp,), TRASH_PAGE, np.int32)
-        wpids[lo:hi] = self.kv.table[row, lo:hi]
-        self.pages = self._page_copy(self.pages, c1["k"], c1["v"],
-                                     jnp.asarray(wpids))
+        with tr.span("schedule"):
+            remaining = len(st.feed) - (st.pos if st.chunkable else 0)
+            c = remaining if (self.prefill_chunk is None
+                              or not st.chunkable) \
+                else min(self.prefill_chunk, remaining)
+            cache = st.cache if st.cache is not None \
+                else self._prefill_cache
+            if st.chunkable:
+                start, bucket, real = self._chunk_shape(st.pos, c)
+                if start != st.pos:
+                    # slid-back window: rewind the write index;
+                    # positions [start, pos) recompute to the same bytes
+                    cache = dict(cache,
+                                 index=jnp.asarray(start, jnp.int32))
+                toks = st.feed[start:start + real]
+                prompt = np.pad(toks, (0, bucket - real))
+                batch = {"tokens": jnp.asarray(prompt[None, :]),
+                         "cache": cache,
+                         "length": jnp.asarray(real, jnp.int32)}
+            else:
+                real = len(st.feed)
+                batch = {"tokens": jnp.asarray(st.feed[None, :]),
+                         "cache": cache}
+                if req.extras:
+                    batch.update({k: jnp.asarray(v) for k, v in
+                                  req.extras.items()})
+        with tr.span("prefill"):
+            logits, c1 = self._prefill(self.params, batch)
+            if self._shard_counts is not None:
+                self._shard_counts["prefill_dispatches"] += 1
+            st.cache = c1
+            with tr.span(WAIT):
+                new_pos = int(np.asarray(c1["index"]))
+            # land the freshly computed positions' pages; shared prefix
+            # pages (slots below first_private_page) are never rewritten
+            # — write targets outside the chunk resolve to the trash page
+            lo = max(st.pos // self.kv.page_size,
+                     self.kv.first_private_page(row))
+            hi = self.kv.pages_for(new_pos)
+            wpids = np.full((self.kv.maxp,), TRASH_PAGE, np.int32)
+            wpids[lo:hi] = self.kv.table[row, lo:hi]
+            self.pages = self._page_copy(self.pages, c1["k"], c1["v"],
+                                         jnp.asarray(wpids))
         st.pos = new_pos
-        self._h_chunk.observe(time.perf_counter() - t0)
-        if self.tracer.enabled:
-            self.tracer.complete(REQUEST_PID, req.uid, "prefill_chunk",
-                                 tr0, start=pos0, end=st.pos)
+        self._note_chunk(req, real)
+        if tr.enabled:
+            tr.complete(REQUEST_PID, req.uid, "prefill_chunk", tr0,
+                        start=pos0, end=st.pos)
         if st.pos < st.target:
             return
-        self._complete_prefill(row, logits[:, -1])
+        self._complete_prefills([(row, logits[:, -1])])
 
-    def _complete_prefill(self, row: int, last_logits) -> None:
-        """Prefill complete: publish the feed's full pages for reuse
+    def _note_chunk(self, req: Request, tokens: int) -> None:
+        """A prefill chunk of ``tokens`` advanced ``req`` in this step."""
+        step = self.tracer.current
+        if step is not None:
+            step.prefill_rows += 1
+            step.prefill_tokens += tokens
+        self._note_step(req)
+
+    def _note_step(self, req: Request) -> None:
+        """The step in progress advanced ``req``."""
+        step = self.tracer.current
+        if step is not None and (not req.steps or req.steps[-1] is not step):
+            req.steps.append(step)
+
+    def _complete_prefills(self, done: List[Tuple[int, Any]]) -> None:
+        """Prefill complete for each ``(row, last_logits)`` in order
+        (``last_logits``: (1, V) last-real-position logits): sample each
+        row's first token, then publish the feed's full pages for reuse
         (the partial boundary page is published at release, once decode
-        stops writing it), sample the first token off ``last_logits``
-        ((1, V) last-real-position logits), and hand the row to
-        decode."""
-        st = self._prefilling.pop(row)
-        req = st.req
-        ids = self._prefix_ids(req)
-        if ids is not None:
-            full = (st.target // self.kv.page_size) * self.kv.page_size
-            self.kv.index_row(row, ids, full)
-        req.status = "running"
-        res = self._run_sampler(last_logits, slice(row, row + 1),
-                                "prefill")
-        self._commit_token(row, req, res, 0)
-        self._note_first_token(req)
+        stops writing it), commit the token and hand the row to
+        decode.  Each row's sampler dispatch reads only its own row of
+        the sampler state, so sampling them all before committing any
+        draws the same tokens."""
+        if not done:
+            return
+        with self.tracer.span("sample"):
+            results = [self._run_sampler(lg, slice(row, row + 1),
+                                         "prefill") for row, lg in done]
+        with self.tracer.span("commit"):
+            for (row, _), res in zip(done, results):
+                st = self._prefilling.pop(row)
+                req = st.req
+                ids = self._prefix_ids(req)
+                if ids is not None:
+                    full = (st.target // self.kv.page_size) \
+                        * self.kv.page_size
+                    self.kv.index_row(row, ids, full)
+                req.status = "running"
+                self._commit_token(row, req, res, 0)
+                self._note_first_token(req)
 
     def _advance_rows(self, rows_: List[int]) -> int:
         """Advance each row's prefill by one chunk step.  Chunkable rows
@@ -955,9 +995,9 @@ class Engine:
         done: Dict[int, Any] = {}
         for bucket in sorted(groups):
             self._dispatch_prefill_batch(bucket, groups[bucket], done)
-        for row in rows_:            # admission order, like sequential
-            if row in done:
-                self._complete_prefill(row, done[row])
+        # admission order, like sequential
+        self._complete_prefills([(row, done[row]) for row in rows_
+                                 if row in done])
         return len(rows_)
 
     def _dispatch_prefill_batch(self, bucket: int,
@@ -971,59 +1011,54 @@ class Engine:
         batch >= 2, so padding rows are free).  Rows that reach target
         stash their (1, V) last-position logits in ``done`` for ordered
         completion by the caller."""
-        t0 = time.perf_counter()
-        tr0 = self.tracer.now()
+        tr = self.tracer
+        tr0 = tr.now()
         n = len(entries)
-        n_pad = max(2, 1 << (n - 1).bit_length())
-        toks = np.zeros((n_pad, bucket), np.int32)
-        starts = np.zeros((n_pad,), np.int32)
-        counts = np.zeros((n_pad,), np.int32)
-        wfrom = np.zeros((n_pad,), np.int32)
-        tables = np.full((n_pad, self.kv.maxp), TRASH_PAGE, np.int32)
-        for j, (row, start, real) in enumerate(entries):
-            st = self._prefilling[row]
-            toks[j, :real] = st.feed[start:start + real]
-            starts[j] = start
-            counts[j] = real
-            # write protection: positions below the first private page
-            # (shared prefix) — or below this chunk's landing floor —
-            # must not be rewritten; slide-back recomputes land bitwise-
-            # equal bytes so rewriting them above the floor is safe
-            lo = max(st.pos // self.kv.page_size,
-                     self.kv.first_private_page(row))
-            wfrom[j] = lo * self.kv.page_size
-            tables[j] = self.kv.table[row]
-        x_last, self.pages = self._prefill_batched(
-            self.params, jnp.asarray(toks), self.pages,
-            jnp.asarray(tables), jnp.asarray(starts),
-            jnp.asarray(counts), jnp.asarray(wfrom))
-        dt = time.perf_counter() - t0
+        with tr.span("schedule"):
+            n_pad = max(2, 1 << (n - 1).bit_length())
+            toks = np.zeros((n_pad, bucket), np.int32)
+            starts = np.zeros((n_pad,), np.int32)
+            counts = np.zeros((n_pad,), np.int32)
+            wfrom = np.zeros((n_pad,), np.int32)
+            tables = np.full((n_pad, self.kv.maxp), TRASH_PAGE, np.int32)
+            for j, (row, start, real) in enumerate(entries):
+                st = self._prefilling[row]
+                toks[j, :real] = st.feed[start:start + real]
+                starts[j] = start
+                counts[j] = real
+                # write protection: positions below the first private
+                # page (shared prefix) — or below this chunk's landing
+                # floor — must not be rewritten; slide-back recomputes
+                # land bitwise-equal bytes so rewriting them above the
+                # floor is safe
+                lo = max(st.pos // self.kv.page_size,
+                         self.kv.first_private_page(row))
+                wfrom[j] = lo * self.kv.page_size
+                tables[j] = self.kv.table[row]
+        with tr.span("prefill"):
+            x_last, self.pages = self._prefill_batched(
+                self.params, jnp.asarray(toks), self.pages,
+                jnp.asarray(tables), jnp.asarray(starts),
+                jnp.asarray(counts), jnp.asarray(wfrom))
+            for j, (row, start, real) in enumerate(entries):
+                if start + real >= self._prefilling[row].target:
+                    # per-row LM head at the sequential path's exact M=1
+                    # dispatch shape (see __init__: bitwise parity)
+                    done[row] = self._logits_head(
+                        self.params, x_last[j:j + 1])[:, -1]
         self._pb_counts["dispatches"] += 1
         if self._shard_counts is not None:
             self._shard_counts["prefill_dispatches"] += 1
         self._pb_counts["rows"] += n
         self._pb_counts["tokens"] += int(counts.sum())
-        self._h_pbatch.observe(dt)
-        if self.tracer.enabled:
-            self.tracer.complete(ENGINE_PID, 0, "prefill_batch", tr0,
-                                 rows=n, bucket=bucket)
-        for j, (row, start, real) in enumerate(entries):
+        for row, start, real in entries:
             st = self._prefilling[row]
             pos0 = st.pos
             st.pos = start + real
-            # the chunk histogram keeps per-row-step count semantics
-            # (one observation per chunk step, like the sequential
-            # path); the batch histogram carries the fused wall time
-            self._h_chunk.observe(dt)
-            if self.tracer.enabled:
-                self.tracer.complete(REQUEST_PID, st.req.uid,
-                                     "prefill_chunk", tr0,
-                                     start=pos0, end=st.pos)
-            if st.pos >= st.target:
-                # per-row LM head at the sequential path's exact M=1
-                # dispatch shape (see __init__: bitwise parity)
-                done[row] = self._logits_head(
-                    self.params, x_last[j:j + 1])[:, -1]
+            self._note_chunk(st.req, real)
+            if tr.enabled:
+                tr.complete(REQUEST_PID, st.req.uid, "prefill_chunk", tr0,
+                            start=pos0, end=st.pos)
 
     def _note_first_token(self, req: Request) -> None:
         if req.first_token_mono is None:
@@ -1039,6 +1074,7 @@ class Engine:
                             now: float) -> None:
         """Non-paged kinds (rwkv/zamba/encdec): monolithic prefill into
         the batched dense cache (the pre-chunking path)."""
+        tr = self.tracer
         feed = self._feed(req)
         p = len(feed)
         if self._can_bucket(req):
@@ -1054,23 +1090,28 @@ class Engine:
         if req.extras:
             batch.update({k: jnp.asarray(v) for k, v in
                           req.extras.items()})
-        logits, c1 = self._prefill(self.params, batch)
-        pos = int(np.asarray(c1["index"]))
-        self.cache = _slot_update(self.cache, c1, row)
-        if self.per_row:
-            self.cache["index"] = self.cache["index"].at[row].set(pos)
-        else:
-            self.cache["index"] = c1["index"]
+        with tr.span("prefill"):
+            logits, c1 = self._prefill(self.params, batch)
+            with tr.span(WAIT):
+                pos = int(np.asarray(c1["index"]))
+            self.cache = _slot_update(self.cache, c1, row)
+            if self.per_row:
+                self.cache["index"] = self.cache["index"].at[row].set(pos)
+            else:
+                self.cache["index"] = c1["index"]
         self.rows[row] = req
         self._sampler_state.bind(row, req)
         self._seq += 1
         self._row_seq[row] = self._seq
         req.status = "running"
         self._note_admitted(req, now)
-        res = self._run_sampler(logits[:, -1], slice(row, row + 1),
-                                "prefill")
-        self._commit_token(row, req, res, 0)
-        self._note_first_token(req)
+        self._note_chunk(req, p)
+        with tr.span("sample"):
+            res = self._run_sampler(logits[:, -1], slice(row, row + 1),
+                                    "prefill")
+        with tr.span("commit"):
+            self._commit_token(row, req, res, 0)
+            self._note_first_token(req)
 
     def _run_sampler(self, logits, sl: slice, kind: str
                      ) -> Dict[str, np.ndarray]:
@@ -1099,6 +1140,7 @@ class Engine:
                 res["topk_logprobs"][j][:kk].tolist())))
         self._sampler_state.note(row, tok)
         self._tokens[row, 0] = tok
+        self._note_step(req)
 
     # ------------------------------------------------------------------
     def _history_ids(self, row: int) -> np.ndarray:
@@ -1198,57 +1240,67 @@ class Engine:
         """Perform queued copy-on-write page copies before anything
         writes the pool (decode's token write must hit the private
         replica, never the shared original)."""
-        for src, dst in self.kv.pending_copies:
-            self.pages = self._cow_copy(self.pages,
-                                        jnp.asarray(src, jnp.int32),
-                                        jnp.asarray(dst, jnp.int32))
-        self.kv.pending_copies.clear()
+        if not self.kv.pending_copies:
+            return
+        with self.tracer.span("cow"):
+            for src, dst in self.kv.pending_copies:
+                self.pages = self._cow_copy(self.pages,
+                                            jnp.asarray(src, jnp.int32),
+                                            jnp.asarray(dst, jnp.int32))
+            self.kv.pending_copies.clear()
 
     # ------------------------------------------------------------------
     def step(self) -> int:
         """One engine tick: expire, admit/advance prefills, decode all
-        running rows, retire.  Returns the number of rows decoded."""
-        self._counts["ticks"] += 1
-        tick_tr0 = self.tracer.now()
-        decoded = self._step_inner()
-        if self.paged:
-            self._g_pages_used.set(self.kv.alloc.num_used)
-            self._g_pages_free.set(self.kv.alloc.num_free)
-            if self._g_pages_held is not None:
-                self._g_pages_held.set(self.kv.pages_held())
-        if self.tracer.enabled:
-            self.tracer.complete(ENGINE_PID, 0, "tick", tick_tr0,
-                                 decoded=decoded)
+        running rows, retire.  Returns the number of rows decoded.  The
+        tick is one ``engine.step`` of the tracer, its `Step` account
+        observed into ``engine.step_s`` and ``engine.step_host_s``."""
+        tr = self.tracer
+        with tr.step(**self._step_args) as step:
+            self._counts["ticks"] += 1
+            decoded = self._step_inner(step)
+            if self.paged:
+                with tr.span("commit"):
+                    self._g_pages_used.set(self.kv.alloc.num_used)
+                    self._g_pages_free.set(self.kv.alloc.num_free)
+                    if self._g_pages_held is not None:
+                        self._g_pages_held.set(self.kv.pages_held())
+        self._h_step.observe(step.t1 - step.t0)
+        self._h_step_host.observe(step.host_s)
         return decoded
 
-    def _step_inner(self) -> int:
-        now = _now_mono()
-        for r in self.sched.expire(now, model=self.model_tag):
-            r.status = "expired"       # scheduler set finish_reason
-            # stamp the finish clocks like _finish does: a streaming
-            # client's terminal "deadline" delta and the latency math
-            # must see real marks, not None
-            r.finish_mono = now
-            r.finish_time = _now_wall()
-            self._counts["failed"] += 1
-            self._finish_counts[FINISH_DEADLINE] += 1
-            if self.tracer.enabled:
-                self.tracer.end(REQUEST_PID, r.uid, "queued")
-                self.tracer.end(REQUEST_PID, r.uid, "request",
-                                finish=FINISH_DEADLINE)
-            self._failed.append(r)
+    def _step_inner(self, step: Step) -> int:
+        tr = self.tracer
+        with tr.span("schedule"):
+            now = _now_mono()
+            for r in self.sched.expire(now, model=self.model_tag):
+                r.status = "expired"    # scheduler set finish_reason
+                # stamp the finish clocks like _finish does: a streaming
+                # client's terminal "deadline" delta and the latency
+                # math must see real marks, not None
+                r.finish_mono = now
+                r.finish_time = _now_wall()
+                self._counts["failed"] += 1
+                self._finish_counts[FINISH_DEADLINE] += 1
+                if tr.enabled:
+                    tr.end(REQUEST_PID, r.uid, "queued")
+                    tr.end(REQUEST_PID, r.uid, "request",
+                           finish=FINISH_DEADLINE)
+                self._failed.append(r)
         chunks = self._admit(now)
-        # retire BEFORE decoding: a prefill that already satisfied the
-        # request (max_new_tokens == 1, or EOS as the first token) must
-        # not decode a surplus token
-        self._retire()
-        active = [i for i, r in enumerate(self.rows)
-                  if r is not None and i not in self._prefilling]
-        if not active:
-            self.sched.account(chunks, 0)
-            return 0
+        with tr.span("commit"):
+            # retire BEFORE decoding: a prefill that already satisfied
+            # the request (max_new_tokens == 1, or EOS as the first
+            # token) must not decode a surplus token
+            self._retire()
+            active = [i for i, r in enumerate(self.rows)
+                      if r is not None and i not in self._prefilling]
+            if not active:
+                self.sched.account(chunks, 0)
+                return 0
         if self.paged:
-            active = self._ensure_room(active)
+            with tr.span("schedule"):
+                active = self._ensure_room(active)
             # drain queued COW copies in the SAME tick they were queued,
             # even when every row got preempted: a stale copy whose
             # target was released and re-allocated next tick would
@@ -1257,53 +1309,52 @@ class Engine:
             if not active:
                 self.sched.account(chunks, 0)
                 return 0
-            t_dec = time.perf_counter()
-            dec_tr0 = self.tracer.now()
             if self.spec is not None:
                 # speculative tick: draft-propose + block-verify commits
                 # 1..spec_k+1 tokens per row, bitwise what the baseline
                 # path below would have emitted (spec_decode)
                 self.spec.tick(active)
             else:
-                table, lengths = self.kv.table, self.kv.lengths
-                if self._prefilling:
-                    # rows mid-prefill must not write garbage K/V into
-                    # their (real) pages, nor attend: point them at the
-                    # trash page
-                    table = table.copy()
-                    lengths = lengths.copy()
-                    for i in self._prefilling:
-                        table[i, :] = TRASH_PAGE
-                        lengths[i] = 0
-                logits, self.pages = self._decode_paged(
-                    self.params, jnp.asarray(self._tokens), self.pages,
-                    jnp.asarray(table), jnp.asarray(lengths))
-                if self._shard_counts is not None:
-                    self._shard_counts["decode_dispatches"] += 1
+                with tr.span("decode"):
+                    table, lengths = self.kv.table, self.kv.lengths
+                    if self._prefilling:
+                        # rows mid-prefill must not write garbage K/V
+                        # into their (real) pages, nor attend: point
+                        # them at the trash page
+                        table = table.copy()
+                        lengths = lengths.copy()
+                        for i in self._prefilling:
+                            table[i, :] = TRASH_PAGE
+                            lengths[i] = 0
+                    logits, self.pages = self._decode_paged(
+                        self.params, jnp.asarray(self._tokens), self.pages,
+                        jnp.asarray(table), jnp.asarray(lengths))
+                    if self._shard_counts is not None:
+                        self._shard_counts["decode_dispatches"] += 1
                 # ONE fused dispatch for the whole decode batch;
                 # inactive rows are sampled-and-discarded (the counter-
                 # based PRNG makes discarded draws side-effect free)
+                with tr.span("sample"):
+                    res = self._run_sampler(logits[:, -1], slice(None),
+                                            "decode")
+                with tr.span("commit"):
+                    for i in active:
+                        self.kv.advance(i)
+                        self._commit_token(i, self.rows[i], res, i)
+        else:
+            with tr.span("decode"):
+                logits, self.cache = self._decode(
+                    self.params, jnp.asarray(self._tokens), self.cache)
+            with tr.span("sample"):
                 res = self._run_sampler(logits[:, -1], slice(None),
                                         "decode")
+            with tr.span("commit"):
                 for i in active:
-                    self.kv.advance(i)
                     self._commit_token(i, self.rows[i], res, i)
-        else:
-            t_dec = time.perf_counter()
-            dec_tr0 = self.tracer.now()
-            logits, self.cache = self._decode(
-                self.params, jnp.asarray(self._tokens), self.cache)
-            res = self._run_sampler(logits[:, -1], slice(None), "decode")
-            for i in active:
-                self._commit_token(i, self.rows[i], res, i)
-        self._h_tick.observe(time.perf_counter() - t_dec)
-        if self.tracer.enabled:
-            for i in active:
-                self.tracer.complete(REQUEST_PID, self.rows[i].uid,
-                                     "decode_tick", dec_tr0,
-                                     token=int(self._tokens[i, 0]))
-        self._retire()
-        self.sched.account(chunks, len(active))
+        step.decode_rows = len(active)
+        with tr.span("commit"):
+            self._retire()
+            self.sched.account(chunks, len(active))
         return len(active)
 
     def _stop_reason(self, r: Request) -> Optional[str]:

@@ -37,13 +37,14 @@ rebinds to the identical state) and advanced per committed token.
 `FusedSampler` owns the dispatch surface the engine drives: the state,
 the bounded menu of jitted specializations, and the sampler's
 observability — dispatch counters (``sampler.dispatches.*``) and a
-dispatch-latency histogram (``sampler.dispatch_s``) published into the
-engine's metrics registry (`repro.obs.metrics`), with per-dispatch
-trace slices on the engine track when tracing is enabled.
+histogram of each dispatch's host seconds (``sampler.dispatch_s``:
+inputs and dispatch, less the ``wait`` span around its results)
+published into the engine's metrics registry (`repro.obs.metrics`).
+The engine puts every dispatch inside its step's ``sample`` phase
+(`repro.obs.trace`).
 """
 from __future__ import annotations
 
-import functools
 import time
 from typing import Dict, List, Optional
 
@@ -53,7 +54,7 @@ import numpy as np
 
 from repro.kernels.topk import NEG, topk_mask
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import ENGINE_PID, Tracer
+from repro.obs.trace import WAIT, Tracer
 
 # Fixed base key for the per-request counter streams; per-row keys are
 # fold_in(fold_in(_BASE, seed), position).  Changing this constant
@@ -80,16 +81,17 @@ def topp_mask(z, p, fill=NEG):
     keeps the top-1), then admit every token whose probability ties the
     cutoff.  ``p >= 1`` disables the row.  Same semantics as
     `kernels.ref.topp_mask_ref` (the numpy walk oracle)."""
-    probs = jax.nn.softmax(z.astype(jnp.float32), axis=-1)
-    srt = jnp.sort(probs, axis=-1)[:, ::-1]              # descending
-    # exclusive cumsum: mass of strictly-higher-ranked tokens
-    excl = jnp.concatenate(
-        [jnp.zeros_like(srt[:, :1]), jnp.cumsum(srt, axis=-1)[:, :-1]],
-        axis=-1)
-    keep_srt = excl < p[:, None]
-    cutoff = jnp.min(jnp.where(keep_srt, srt, 2.0), axis=-1)
-    keep = (probs >= cutoff[:, None]) | (p >= 1.0)[:, None]
-    return jnp.where(keep, z, jnp.asarray(fill, z.dtype))
+    with jax.named_scope("top_p"):
+        probs = jax.nn.softmax(z.astype(jnp.float32), axis=-1)
+        srt = jnp.sort(probs, axis=-1)[:, ::-1]          # descending
+        # exclusive cumsum: mass of strictly-higher-ranked tokens
+        excl = jnp.concatenate(
+            [jnp.zeros_like(srt[:, :1]),
+             jnp.cumsum(srt, axis=-1)[:, :-1]], axis=-1)
+        keep_srt = excl < p[:, None]
+        cutoff = jnp.min(jnp.where(keep_srt, srt, 2.0), axis=-1)
+        keep = (probs >= cutoff[:, None]) | (p >= 1.0)[:, None]
+        return jnp.where(keep, z, jnp.asarray(fill, z.dtype))
 
 
 def minp_mask(z, min_p, fill=NEG):
@@ -271,8 +273,9 @@ class FusedSampler:
     """The engine-facing fused-sampler dispatch surface.
 
     Holds the per-row `SamplerState`, the bounded menu of compiled
-    `sample_tokens` specializations keyed by (logprob width,
-    any-sampled-row, any-truncated-row), and the sampler's metrics:
+    `sample_tokens` specializations (one program, ``jit_sample_tokens``,
+    keyed statically by logprob width, any-sampled-row and
+    any-truncated-row), and the sampler's metrics:
     the engine dispatches the k=0 variant (no per-tick top-K) unless
     some bound row asked for logprobs, the ``with_sampling=False``
     variant (argmax only — no Gumbel field) when every bound row is
@@ -282,8 +285,7 @@ class FusedSampler:
     penalties — sparing the (rows, vocab) host->device transfer on
     default traffic.  All variants are bitwise token-identical (greedy
     rows take argmax in every variant; disabled knobs are exact
-    no-ops).  (trunc only matters when samp; the samp=False entries for
-    trunc=True just alias the same compiled program shape.)
+    no-ops).
     """
 
     def __init__(self, rows: int, vocab: int, logprob_k: int = 8, *,
@@ -293,45 +295,44 @@ class FusedSampler:
         self.state = SamplerState(rows, vocab)
         self.logprob_k = int(min(logprob_k, vocab))
         self.tracer = tracer if tracer is not None else Tracer(enabled=False)
-        self._fns = {
-            (k, samp, trunc): jax.jit(functools.partial(
-                sample_tokens, logprob_k=k,
-                with_sampling=samp, with_truncation=trunc))
-            for k in {0, self.logprob_k}
-            for samp in (False, True) for trunc in (False, True)}
+        self._sample = jax.jit(sample_tokens, static_argnames=(
+            "logprob_k", "with_sampling", "with_truncation"))
         self.dispatches = m.group("sampler.dispatches",
                                   keys=("prefill", "decode", "verify"))
         self._h_dispatch = m.histogram("sampler.dispatch_s")
 
     @property
     def time_s(self) -> float:
-        """Cumulative seconds spent inside sampler dispatches."""
+        """Cumulative host seconds of sampler dispatches (waits for
+        their results left out)."""
         return self._h_dispatch.total
+
+    def _dispatch(self, logits, batch, sl: slice, kind: str, t0: float
+                  ) -> Dict[str, np.ndarray]:
+        """Dispatch the specialization the rows of ``sl`` need, wait
+        for its results, and account the dispatch begun at ``t0``."""
+        st = self.state
+        samp = bool(st.is_sampled[sl].any())
+        out = self._sample(
+            logits, batch,
+            logprob_k=self.logprob_k if st.wants_logprobs[sl].any() else 0,
+            with_sampling=samp,
+            with_truncation=samp and bool(st.uses_truncation[sl].any()))
+        with self.tracer.span(WAIT) as w:
+            res = {k: np.asarray(v) for k, v in out.items()}
+        self._h_dispatch.observe(time.monotonic() - t0 - w.dur_s)
+        self.dispatches[kind] += 1
+        return res
 
     def run(self, logits, sl: slice, kind: str) -> Dict[str, np.ndarray]:
         """One fused dispatch over the row slice ``sl`` of the state
         (full batch for decode ticks, the single admitted row for a
         prefill completion)."""
-        # sync the model's (async-dispatched) logits BEFORE the clock
-        # starts, so sampler.dispatch_s measures the sampler, not the
-        # decode forward pass it would otherwise absorb
-        logits = jax.block_until_ready(jnp.asarray(logits, jnp.float32))
-        t0 = time.perf_counter()
-        tr0 = self.tracer.now()
-        st = self.state
-        masks = bool(st.uses_penalties[sl].any())
-        k = self.logprob_k if st.wants_logprobs[sl].any() else 0
-        samp = bool(st.is_sampled[sl].any())
-        trunc = samp and bool(st.uses_truncation[sl].any())
-        out = self._fns[k, samp, trunc](
-            logits, st.batch(sl, with_masks=masks))
-        res = {k2: np.asarray(v) for k2, v in out.items()}
-        self._h_dispatch.observe(time.perf_counter() - t0)
-        self.dispatches[kind] += 1
-        if self.tracer.enabled:
-            self.tracer.complete(ENGINE_PID, 0, f"sampler:{kind}", tr0)
-        return res
-
+        t0 = time.monotonic()
+        masks = bool(self.state.uses_penalties[sl].any())
+        return self._dispatch(jnp.asarray(logits, jnp.float32),
+                              self.state.batch(sl, with_masks=masks),
+                              sl, kind, t0)
 
     def run_block(self, logits, sl: slice, proposals: np.ndarray,
                   kind: str = "verify") -> Dict[str, np.ndarray]:
@@ -349,16 +350,12 @@ class FusedSampler:
         (R*S,) result arrays (slot ``r*S + s``), matching what
         ``run`` returns for a batch of R*S rows.
         """
-        logits = jax.block_until_ready(jnp.asarray(logits, jnp.float32))
-        t0 = time.perf_counter()
-        tr0 = self.tracer.now()
+        t0 = time.monotonic()
+        logits = jnp.asarray(logits, jnp.float32)
         st = self.state
         r, s_blk, vocab = logits.shape
         proposals = np.asarray(proposals, np.int32).reshape(r, s_blk - 1)
         masks = bool(st.uses_penalties[sl].any())
-        k = self.logprob_k if st.wants_logprobs[sl].any() else 0
-        samp = bool(st.is_sampled[sl].any())
-        trunc = samp and bool(st.uses_truncation[sl].any())
 
         exp = {key: np.repeat(v, s_blk, axis=0)
                for key, v in st.batch(sl, with_masks=False).items()}
@@ -377,14 +374,8 @@ class FusedSampler:
                 out_seen[s::s_blk] |= cum
             exp["seen"], exp["out_seen"] = seen, out_seen
 
-        out = self._fns[k, samp, trunc](logits.reshape(r * s_blk, vocab),
-                                        exp)
-        res = {k2: np.asarray(v) for k2, v in out.items()}
-        self._h_dispatch.observe(time.perf_counter() - t0)
-        self.dispatches[kind] += 1
-        if self.tracer.enabled:
-            self.tracer.complete(ENGINE_PID, 0, f"sampler:{kind}", tr0)
-        return res
+        return self._dispatch(logits.reshape(r * s_blk, vocab), exp, sl,
+                              kind, t0)
 
 
 def accept_counts(targets: np.ndarray, proposals: np.ndarray,

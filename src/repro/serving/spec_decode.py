@@ -40,11 +40,12 @@ too tight for a row's k+1 verify writes the block just shrinks (down
 to 1 == baseline) instead of preempting anyone.
 
 Observability: ``spec.*`` counters (proposed/accepted/dispatches), an
-accept-length histogram, and propose/verify/rollback tracer spans.
+accept-length histogram, the step phases ``spec_propose`` and
+``spec_verify`` (`repro.obs.trace`), and ``spec_rollback`` instants on
+the request track.
 """
 from __future__ import annotations
 
-import functools
 from typing import Dict, List
 
 import jax
@@ -52,7 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import ENGINE_PID, REQUEST_PID
+from repro.obs.trace import REQUEST_PID, WAIT
 from repro.serving import sampling as sampling_lib
 from repro.serving.paged_cache import TRASH_PAGE, PagedKVCache
 
@@ -116,19 +117,20 @@ class SpecDecoder:
             raise ValueError("speculative decoding needs "
                              "decode_paged_block (decoder, non-MoE)")
         impl = attn_impl
-        # catch-up: pages-only (XLA DCEs the LM head)
-        self._catchup = jax.jit(
-            lambda dp, t, pg, tb, ln, ct: blk(dp, t, pg, tb, ln, ct,
-                                              impl)[1],
-            donate_argnums=(2,))
-        self._verify = jax.jit(
-            lambda p, t, pg, tb, ln, ct: base_blk(p, t, pg, tb, ln, ct,
-                                                  impl),
-            donate_argnums=(2,))
 
-        def propose_body(dparams, catch_tokens, lengths, counts,
-                         step_mask, pages, table, knobs, pmasks, *,
-                         masks, samp, trunc, k):
+        def draft_catchup(dp, t, pg, tb, ln, ct):
+            # pages only (XLA DCEs the LM head)
+            return blk(dp, t, pg, tb, ln, ct, impl)[1]
+
+        def verify_block(p, t, pg, tb, ln, ct):
+            return base_blk(p, t, pg, tb, ln, ct, impl)
+
+        self._catchup = jax.jit(draft_catchup, donate_argnums=(2,))
+        self._verify = jax.jit(verify_block, donate_argnums=(2,))
+
+        def draft_propose(dparams, catch_tokens, lengths, counts,
+                          step_mask, pages, table, knobs, pmasks, *,
+                          masks, samp, trunc, k):
             """Fused draft tick: catch-up block + k sample/decode steps.
 
             The sampling stages mirror the base sampler exactly — same
@@ -166,21 +168,12 @@ class SpecDecoder:
                     cur = cur + step_mask
             return jnp.stack(props, 1), pages
 
-        # per-k propose jits, built lazily: fixed-k engines only ever
-        # key (k_max, ...); the adaptive controller adds a key per depth
-        # it actually visits
-        self._propose_body = propose_body
-        self._propose_fns: Dict[tuple, object] = {}
-
-    def _propose_fn(self, k: int, masks: bool, samp: bool, trunc: bool):
-        key = (k, masks, samp, trunc)
-        fn = self._propose_fns.get(key)
-        if fn is None:
-            fn = jax.jit(functools.partial(
-                self._propose_body, masks=masks, samp=samp, trunc=trunc,
-                k=k), donate_argnums=(5,))
-            self._propose_fns[key] = fn
-        return fn
+        # one program per (k, masks, samp, trunc) actually used:
+        # fixed-k engines only ever key k_max; the adaptive controller
+        # adds a key per depth it visits
+        self._propose = jax.jit(
+            draft_propose, static_argnames=("masks", "samp", "trunc", "k"),
+            donate_argnums=(5,))
 
     # ------------------------------------------------------------------
     def _history(self, i: int, upto: int) -> np.ndarray:
@@ -204,31 +197,33 @@ class SpecDecoder:
         after the engine's room/COW pass.  Returns committed tokens.
         """
         eng, k = self.eng, self.k
+        tr = self.tracer
         S = k + 1
         B = eng.n_rows
         kv_b = eng.kv
 
         # ---- per-row verify limits --------------------------------
-        limits = np.zeros((B,), np.int64)
-        elig: List[int] = []
-        for i in active:
-            n = int(kv_b.lengths[i])
-            req = eng.rows[i]
-            want = min(S, eng.max_len - n,
-                       req.sampling.max_tokens - len(req.tokens))
-            v = max(want, 1)
-            # best-effort room for the k+1-position write block: on
-            # pool pressure shrink the block (never preempt for spec)
-            while v > 1 and kv_b.ensure_room(i, n + v) != "ok":
-                v -= 1
-            limits[i] = v
-            # extras rows (image tokens) can't be replayed from token
-            # ids alone, so the draft skips them: verify-only == one
-            # baseline-equivalent token per tick
-            if v >= 2 and n + k <= eng.max_len and not req.extras:
-                elig.append(i)
-            else:
-                self.counts["baseline_rows"] += 1
+        with tr.span("schedule"):
+            limits = np.zeros((B,), np.int64)
+            elig: List[int] = []
+            for i in active:
+                n = int(kv_b.lengths[i])
+                req = eng.rows[i]
+                want = min(S, eng.max_len - n,
+                           req.sampling.max_tokens - len(req.tokens))
+                v = max(want, 1)
+                # best-effort room for the k+1-position write block: on
+                # pool pressure shrink the block (never preempt for spec)
+                while v > 1 and kv_b.ensure_room(i, n + v) != "ok":
+                    v -= 1
+                limits[i] = v
+                # extras rows (image tokens) can't be replayed from token
+                # ids alone, so the draft skips them: verify-only == one
+                # baseline-equivalent token per tick
+                if v >= 2 and n + k <= eng.max_len and not req.extras:
+                    elig.append(i)
+                else:
+                    self.counts["baseline_rows"] += 1
         # a second COW should be impossible here (the engine's
         # ensure-room pass privatized every cursor page) but drain
         # defensively: a queued copy must land before the block write
@@ -249,83 +244,101 @@ class SpecDecoder:
 
         # ---- draft catch-up + propose (eligible rows only) --------
         if elig:
-            tr0 = self.tracer.now()
-            rem = np.zeros((B,), np.int64)
-            hist: Dict[int, np.ndarray] = {}
-            for i in elig:
-                n = int(kv_b.lengths[i])
-                if i not in self.kv.row_pages:
-                    ok = self.kv.admit_row(i, 0)
-                    assert ok, "draft pool is fully provisioned"
-                st = self.kv.ensure_room(i, n + k)
-                assert st == "ok", f"draft room: {st}"
-                hist[i] = self._history(i, n + 1)
-                rem[i] = n + 1 - int(self.kv.lengths[i])
-                assert rem[i] >= 1
-            while any(rem[i] > _PROP_CATCH for i in elig):
-                feed = np.zeros((B, _CATCH_CHUNK), np.int32)
-                cnts = np.zeros((B,), np.int32)
-                for i in elig:
-                    if rem[i] > _PROP_CATCH:
-                        c = int(min(_CATCH_CHUNK, rem[i] - _PROP_CATCH))
-                        dl = int(self.kv.lengths[i])
-                        feed[i, :c] = hist[i][dl:dl + c]
-                        cnts[i] = c
-                self.pages = self._catchup(
-                    self.draft_params, jnp.asarray(feed), self.pages,
-                    jnp.asarray(self.kv.table),
-                    jnp.asarray(self.kv.lengths), jnp.asarray(cnts))
-                self.counts["draft_dispatches"] += 1
-                for i in elig:
-                    if cnts[i]:
-                        self.kv.lengths[i] += cnts[i]
-                        rem[i] -= cnts[i]
-            feed = np.zeros((B, _PROP_CATCH), np.int32)
-            cnts = np.zeros((B,), np.int32)
-            step_mask = np.zeros((B,), np.int32)
-            for i in elig:
-                c = int(rem[i])
-                dl = int(self.kv.lengths[i])
-                feed[i, :c] = hist[i][dl:dl + c]
-                cnts[i] = c
-                step_mask[i] = 1
-            knobs = sst.batch(slice(None), with_masks=False)
-            pmasks = {"seen": sst.seen, "out_seen": sst.out_seen} \
-                if masks else {}
-            props, self.pages = self._propose_fn(k, masks, samp, trunc)(
-                self.draft_params, jnp.asarray(feed),
-                jnp.asarray(self.kv.lengths), jnp.asarray(cnts),
-                jnp.asarray(step_mask), self.pages,
-                jnp.asarray(self.kv.table), knobs, pmasks)
-            proposals = np.asarray(props)
-            self.counts["draft_dispatches"] += 1
-            self.counts["proposed"] += k * len(elig)
-            for i in elig:
-                # catch-up wrote `cnts` positions, steps wrote k-1 more
-                self.kv.lengths[i] += int(cnts[i]) + (k - 1)
-            if self.tracer.enabled:
-                self.tracer.complete(ENGINE_PID, 0, "spec:propose", tr0,
-                                     rows=len(elig))
+            with tr.span("spec_propose"):
+                proposals = self._propose_rows(elig, k, masks, samp,
+                                               trunc, sst)
 
         # ---- verify: one base-model block + one fused sampler -----
-        tr1 = self.tracer.now()
-        tokens_blk = np.zeros((B, S), np.int32)
-        tokens_blk[:, 0] = eng._tokens[:, 0]
-        tokens_blk[:, 1:] = proposals
-        counts_v = np.zeros((B,), np.int32)
-        for i in active:
-            counts_v[i] = limits[i]
-        logits, eng.pages = self._verify(
-            eng.params, jnp.asarray(tokens_blk), eng.pages,
-            jnp.asarray(table), jnp.asarray(lengths),
-            jnp.asarray(counts_v))
-        res = eng._sampler.run_block(logits, slice(None), proposals,
-                                     kind="verify")
+        with tr.span("spec_verify"):
+            tokens_blk = np.zeros((B, S), np.int32)
+            tokens_blk[:, 0] = eng._tokens[:, 0]
+            tokens_blk[:, 1:] = proposals
+            counts_v = np.zeros((B,), np.int32)
+            for i in active:
+                counts_v[i] = limits[i]
+            logits, eng.pages = self._verify(
+                eng.params, jnp.asarray(tokens_blk), eng.pages,
+                jnp.asarray(table), jnp.asarray(lengths),
+                jnp.asarray(counts_v))
+        with tr.span("sample"):
+            res = eng._sampler.run_block(logits, slice(None), proposals,
+                                         kind="verify")
         self.counts["verify_dispatches"] += 1
-        if self.tracer.enabled:
-            self.tracer.complete(ENGINE_PID, 0, "spec:verify", tr1,
-                                 rows=len(active))
+        with tr.span("commit"):
+            return self._commit(active, res, proposals, limits, elig)
 
+    def _propose_rows(self, elig: List[int], k: int, masks: bool,
+                      samp: bool, trunc: bool, sst) -> np.ndarray:
+        """Catch the draft's cache up to each eligible row's committed
+        history and sample its ``k`` proposals, (B, k) int32."""
+        B = self.eng.n_rows
+        kv_b = self.eng.kv
+        rem = np.zeros((B,), np.int64)
+        hist: Dict[int, np.ndarray] = {}
+        for i in elig:
+            n = int(kv_b.lengths[i])
+            if i not in self.kv.row_pages:
+                ok = self.kv.admit_row(i, 0)
+                assert ok, "draft pool is fully provisioned"
+            st = self.kv.ensure_room(i, n + k)
+            assert st == "ok", f"draft room: {st}"
+            hist[i] = self._history(i, n + 1)
+            rem[i] = n + 1 - int(self.kv.lengths[i])
+            assert rem[i] >= 1
+        while any(rem[i] > _PROP_CATCH for i in elig):
+            feed = np.zeros((B, _CATCH_CHUNK), np.int32)
+            cnts = np.zeros((B,), np.int32)
+            for i in elig:
+                if rem[i] > _PROP_CATCH:
+                    c = int(min(_CATCH_CHUNK, rem[i] - _PROP_CATCH))
+                    dl = int(self.kv.lengths[i])
+                    feed[i, :c] = hist[i][dl:dl + c]
+                    cnts[i] = c
+            self.pages = self._catchup(
+                self.draft_params, jnp.asarray(feed), self.pages,
+                jnp.asarray(self.kv.table),
+                jnp.asarray(self.kv.lengths), jnp.asarray(cnts))
+            self.counts["draft_dispatches"] += 1
+            for i in elig:
+                if cnts[i]:
+                    self.kv.lengths[i] += cnts[i]
+                    rem[i] -= cnts[i]
+        feed = np.zeros((B, _PROP_CATCH), np.int32)
+        cnts = np.zeros((B,), np.int32)
+        step_mask = np.zeros((B,), np.int32)
+        for i in elig:
+            c = int(rem[i])
+            dl = int(self.kv.lengths[i])
+            feed[i, :c] = hist[i][dl:dl + c]
+            cnts[i] = c
+            step_mask[i] = 1
+        knobs = sst.batch(slice(None), with_masks=False)
+        pmasks = {"seen": sst.seen, "out_seen": sst.out_seen} \
+            if masks else {}
+        props, self.pages = self._propose(
+            self.draft_params, jnp.asarray(feed),
+            jnp.asarray(self.kv.lengths), jnp.asarray(cnts),
+            jnp.asarray(step_mask), self.pages,
+            jnp.asarray(self.kv.table), knobs, pmasks,
+            masks=masks, samp=samp, trunc=trunc, k=k)
+        with self.tracer.span(WAIT):
+            proposals = np.asarray(props)
+        self.counts["draft_dispatches"] += 1
+        self.counts["proposed"] += k * len(elig)
+        for i in elig:
+            # catch-up wrote `cnts` positions, steps wrote k-1 more
+            self.kv.lengths[i] += int(cnts[i]) + (k - 1)
+        return proposals
+
+    def _commit(self, active: List[int], res, proposals: np.ndarray,
+                limits: np.ndarray, elig: List[int]) -> int:
+        """Commit each row's verified targets up to its first draft
+        mismatch, roll both caches back to the commit point, and step
+        the adaptive controller.  Returns committed tokens."""
+        eng, k = self.eng, self.k
+        S = k + 1
+        B = eng.n_rows
+        kv_b = eng.kv
         # ---- accept / commit / rollback ---------------------------
         targets = res["token"].reshape(B, S)
         commits = sampling_lib.accept_counts(targets, proposals, limits)

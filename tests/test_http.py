@@ -142,6 +142,12 @@ def test_http_error_codes(tiny):
     assert out["405"][0] == 405
     assert out["health"][0] == 200
     assert out["metrics"][0] == 200 and "http.requests" in out["metrics"][1]
+    # one observation a step from its account; no dispatch-time histograms
+    assert "engine.step_s" in out["metrics"][1]
+    assert "engine.step_host_s" in out["metrics"][1]
+    for gone in ("engine.decode_tick_s", "engine.prefill_chunk_s",
+                 "engine.prefill_batch_s"):
+        assert gone not in out["metrics"][1]
     assert out["lost"][0] == 404
 
 

@@ -7,15 +7,17 @@ Host-side (no model):
   clamping, snapshot/diff arithmetic,
 - `MetricView` compat surface (legacy ``stats["x"] += 1`` call sites
   publish into the registry),
-- disabled-tracer no-op guarantee; Chrome trace-event JSON round-trip.
+- disabled-tracer guarantee (no events; the step account still kept);
+  Chrome trace-event JSON round-trip.
 
 Engine-level (tiny decoder, real jitted prefill/decode):
 - span nesting stays balanced under preemption-recompute (every
   ``queued``/``request`` B has its E, preempted requests re-open
   ``queued``),
 - tracing is bitwise inert: the same seeded workload emits identical
-  tokens with the tracer off vs on (prefix cache + chunked prefill +
-  an oversubscribed pool — the busiest instrumented paths),
+  tokens with the tracer off vs on, and with a profiler session on
+  (prefix cache + chunked prefill + an oversubscribed pool — the
+  busiest instrumented paths),
 - ``shutdown()`` leak audit: clean engines report zero anomalies,
   corrupted bookkeeping increments ``kv.leak_anomalies`` instead of
   raising — including rows in the speculative draft pool.
@@ -129,7 +131,12 @@ def test_disabled_tracer_records_nothing():
     tr.complete(0, 0, "b", 0.0)
     tr.instant(0, 0, "c")
     tr.end(0, 0, "a")
+    with tr.step() as st:
+        with tr.span("decode"):
+            pass
     assert tr.events == [] and tr._tracks == {}
+    # the step account is kept whatever ``enabled`` says
+    assert st.t1 >= st.t0 and "decode" in st.phase_s
 
 
 def test_trace_export_roundtrip(tmp_path):
@@ -137,7 +144,7 @@ def test_trace_export_roundtrip(tmp_path):
     tr.track(REQUEST_PID, 3, "req 3")
     tr.begin(REQUEST_PID, 3, "request", prompt_len=4)
     t0 = tr.now()
-    tr.complete(ENGINE_PID, 0, "tick", t0, decoded=2)
+    tr.complete(REQUEST_PID, 3, "prefill_chunk", t0, start=0, end=4)
     tr.instant(REQUEST_PID, 3, "first_token")
     tr.end(REQUEST_PID, 3, "request")
     out = tmp_path / "trace.json"
@@ -148,7 +155,7 @@ def test_trace_export_roundtrip(tmp_path):
     names = {e["args"]["name"] for e in ev if e["ph"] == "M"}
     assert {"engine", "requests", "req 3"} <= names
     x = [e for e in ev if e["ph"] == "X"][0]
-    assert x["dur"] >= 0 and x["args"] == {"decoded": 2}
+    assert x["dur"] >= 0 and x["args"] == {"start": 0, "end": 4}
     assert [e["ph"] for e in ev if e["ph"] in "BE"] == ["B", "E"]
     ts = [e["ts"] for e in ev if e["ph"] != "M"]
     assert ts == sorted(ts)
@@ -220,22 +227,31 @@ def test_spans_balance_under_preemption(tiny):
                for (tid, name), (b, _) in per_track.items())
     preempts = [e for e in tr.events if e.get("name") == "preempt"]
     assert len(preempts) == eng.stats()["preemptions"]
-    # engine-track ticks recorded as X slices
+    # engine-track steps recorded as X slices
     assert any(e["ph"] == "X" and e["pid"] == ENGINE_PID
-               and e["name"] == "tick" for e in tr.events)
+               and e["name"] == "engine.step" for e in tr.events)
 
 
+@pytest.mark.parametrize("arm", ["tracer", "profiler"])
 @pytest.mark.parametrize("seed", [0, 3])
-def test_tracing_is_bitwise_inert(tiny, seed):
-    """Same seeded workload, tracer off vs on: identical tokens, even
-    through prefix-cache hits, chunked prefill, and preemption."""
+def test_tracing_is_bitwise_inert(tiny, seed, arm, tmp_path):
+    """Same seeded workload, tracer off vs on (or under a profiler
+    session): identical tokens, even through prefix-cache hits, chunked
+    prefill, and preemption."""
     model, params = tiny
     kw = dict(num_pages=12, seed=seed, n_req=6, max_new=6,
               prefix_cache=True, prefill_chunk=8)
     _, toks_off = _run(model, params, tracer=Tracer(enabled=False), **kw)
-    eng, toks_on = _run(model, params, tracer=Tracer(enabled=True), **kw)
+    if arm == "tracer":
+        eng, toks_on = _run(model, params, tracer=Tracer(enabled=True),
+                            **kw)
+        assert eng.tracer.events       # the traced arm actually recorded
+    else:
+        with jax.profiler.trace(str(tmp_path)):
+            _, toks_on = _run(model, params, tracer=Tracer(enabled=False),
+                              **kw)
+        assert list(tmp_path.rglob("*.xplane.pb"))
     assert toks_on == toks_off
-    assert eng.tracer.events       # the traced arm actually recorded
 
 
 def test_engine_metrics_registry_names(tiny):
@@ -251,6 +267,14 @@ def test_engine_metrics_registry_names(tiny):
     assert snap["engine.tokens"] == sum(len(t) for t in toks.values())
     assert snap["engine.ttft_s"]["count"] == 4
     assert snap["engine.queue_wait_s"]["count"] == 4
+    # one observation a step, from its account; the histograms that
+    # timed asynchronous dispatches are gone
+    assert snap["engine.step_s"]["count"] == snap["engine.ticks"]
+    assert snap["engine.step_host_s"]["count"] == snap["engine.ticks"]
+    assert snap["engine.step_host_s"]["sum"] <= snap["engine.step_s"]["sum"]
+    for gone in ("engine.decode_tick_s", "engine.prefill_chunk_s",
+                 "engine.prefill_batch_s"):
+        assert gone not in snap
     # stats() is a thin view over the same registry
     s = eng.stats()
     assert s["ticks"] == snap["engine.ticks"]
