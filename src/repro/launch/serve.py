@@ -83,10 +83,12 @@ def main() -> int:
     p.add_argument("--deadline", type=float, default=None,
                    help="max queue wait in seconds before a request "
                         "expires unserved")
-    p.add_argument("--attn-impl", default="ref",
+    p.add_argument("--attn-impl", default=None,
                    choices=("ref", "pallas"),
-                   help="paged decode attention: gather oracle or the "
-                        "paged-gather Pallas kernel")
+                   help="paged attention: gather oracle or the Pallas "
+                        "kernels (default: the platform's choice, the "
+                        "decode kernel on a TPU and the oracle "
+                        "elsewhere)")
     p.add_argument("--prefix-cache", action="store_true",
                    help="dedup shared prompt prefixes across requests "
                         "(radix tree over KV pages, refcounts + "

@@ -284,8 +284,8 @@ def apply_paged(plan: AttentionPlan, params, x, *, pages, page_table,
     ``table[b, lengths // ps]`` offset ``lengths % ps``).
 
     Returns (out (B, 1, d_model), (new_pk, new_pv)).  impl: "ref"
-    (gather-then-attend oracle) or "pallas" (paged-gather flash-decode
-    kernel; interpret mode off-TPU).
+    (gather-then-attend oracle) or "pallas" (flash-decode kernel that
+    copies only each row's live pages; interpret mode off-TPU).
     """
     from repro.kernels import paged_attention as PA
     from repro.kernels import ref as KREF

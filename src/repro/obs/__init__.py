@@ -11,8 +11,9 @@ request tracer.
   host block on the device) are ``jax.profiler`` annotations, so a
   profiler session lays them on its own clock over the device's
   operations; each step's account (a `Step`: phase self seconds, wait,
-  host seconds, rows, compiles) lands on the requests it advanced
-  (``Request.steps``) and in ``engine.step_s`` / ``engine.step_host_s``.
+  host seconds, rows, live KV pages read, compiles) lands on the
+  requests it advanced (``Request.steps``) and in ``engine.step_s`` /
+  ``engine.step_host_s``.
   Cost: some tens of microseconds a step.  With ``Tracer(enabled=True)``
   it also records the steps, phases and per-request tracks (queued ->
   prefill chunks -> first token -> preempt/recompute -> finish) as

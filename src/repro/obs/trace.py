@@ -89,6 +89,8 @@ class Step:
     prefill_rows: int = 0       # rows that advanced a prefill chunk
     prefill_tokens: int = 0     # prompt tokens those chunks computed
     decode_rows: int = 0        # rows that decoded
+    attn_pages: int = 0         # KV pages the decode attention read:
+    #                             ceil((len + 1) / page) over those rows
     compiles: int = 0           # compiles and cache loads in the step
 
 

@@ -78,6 +78,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import runtime
 from repro.models.transformer import Model
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import REQUEST_PID, WAIT, Step, Tracer
@@ -261,7 +262,8 @@ class Engine:
                  max_concurrency: Optional[int] = None,
                  page_size: int = 16, num_pages: Optional[int] = None,
                  scheduler: Optional[SchedulerConfig] = None,
-                 attn_impl: str = "ref", paged: Optional[bool] = None,
+                 attn_impl: Optional[str] = None,
+                 paged: Optional[bool] = None,
                  prefix_cache: bool = False,
                  prefill_chunk: Optional[int] = None,
                  batched_prefill: bool = True,
@@ -283,8 +285,13 @@ class Engine:
         ``num_pages`` sizes the physical pool — default fully provisions
         every row to max_len (no preemption possible); pass less to
         oversubscribe memory and let preemption absorb the overflow.
-        ``attn_impl``: "ref" (gather oracle) or "pallas" (paged-gather
-        flash-decode kernel; interpret mode off-TPU).
+        ``attn_impl``: None (the platform's choice: the decode step
+        attends through the paged Pallas kernel on a TPU and through
+        the gather oracle elsewhere; prefill and the speculative block
+        keep the oracle), "ref" (the gather oracle everywhere) or
+        "pallas" (the Pallas kernels everywhere; interpret mode
+        off-TPU).  The choice is made once, here:
+        ``decode_attn_impl`` and ``prefill_attn_impl``.
         ``prefix_cache`` dedups shared prompt prefixes across requests
         (radix tree + refcounts + COW); ``prefill_chunk`` prefills long
         prompts N tokens per tick interleaved with decode (None =
@@ -321,7 +328,10 @@ class Engine:
         draft proposes ``spec_k`` tokens per tick and the base model
         verifies them in one batched dispatch (`repro.serving.
         spec_decode`).  Emitted tokens are bitwise identical to the
-        non-speculative engine for every SamplingParams mix.  Requires
+        non-speculative engine for every SamplingParams mix, at an
+        explicit ``attn_impl`` (under the platform's choice on a TPU
+        the speculative block keeps the oracle while plain decode
+        takes the kernel).  Requires
         the paged backend on a decoder kind (MoE excluded: its
         capacity routing is batch-shape dependent, so block-verify
         parity doesn't hold).
@@ -448,18 +458,28 @@ class Engine:
                                    page_quota=page_quota)
             self._g_pages_used = self.metrics.gauge("kv.pages_in_use")
             self._g_pages_free = self.metrics.gauge("kv.pages_free")
+            # live pages the decode attention read over the rows x MAXP
+            # the table provisions: what the paged kernel reads of what
+            # the gather oracle reads
+            self._g_attn_share = self.metrics.gauge(
+                "decode.attn_live_page_share")
             self._g_pages_held = self.metrics.gauge("kv.pages_held") \
                 if (page_allocator is not None or page_quota is not None) \
                 else None
             self.pages = shared_pages if shared_pages is not None \
                 else model.init_paged_cache(num_pages, page_size)
             self._prefill_cache = model.init_cache(1, self.max_len)
+            self.decode_attn_impl = attn_impl or (
+                "pallas" if runtime.on_tpu() else "ref")
+            self.prefill_attn_impl = attn_impl or "ref"
+            decode_impl = self.decode_attn_impl
+            prefill_impl = self.prefill_attn_impl
             # donate the page pools: without donation the functional
             # pages-in/pages-out contract would copy the whole pool per
             # decode tick / prefill (backends that can't donate just
             # warn and copy — no behavior change)
             def decode_paged(p, t, pg, tb, ln):
-                return model.decode_paged(p, t, pg, tb, ln, attn_impl)
+                return model.decode_paged(p, t, pg, tb, ln, decode_impl)
 
             self._decode_paged = jax.jit(decode_paged, donate_argnums=(2,))
             self._page_copy = jax.jit(_copy_pages, donate_argnums=(0,))
@@ -477,7 +497,7 @@ class Engine:
             if self.batched_prefill:
                 def prefill_paged(p, t, pg, tb, st, cn, wf):
                     return model.prefill_paged(p, t, pg, tb, st, cn, wf,
-                                               attn_impl)
+                                               prefill_impl)
 
                 self._prefill_batched = jax.jit(prefill_paged,
                                                 donate_argnums=(2,))
@@ -491,7 +511,8 @@ class Engine:
                         "(decoder kind, non-MoE)")
                 from repro.serving.spec_decode import SpecDecoder
                 self.spec = SpecDecoder(self, draft[0], draft[1],
-                                        k=spec_k, attn_impl=attn_impl,
+                                        k=spec_k,
+                                        attn_impl=attn_impl or "ref",
                                         adaptive=spec_adaptive)
             if mesh is not None:
                 self._init_mesh(mesh)
@@ -1326,6 +1347,10 @@ class Engine:
                         for i in self._prefilling:
                             table[i, :] = TRASH_PAGE
                             lengths[i] = 0
+                    ps = self.kv.page_size
+                    step.attn_pages = int(
+                        ((lengths[active] + ps) // ps).sum())
+                    self._g_attn_share.set(step.attn_pages / table.size)
                     logits, self.pages = self._decode_paged(
                         self.params, jnp.asarray(self._tokens), self.pages,
                         jnp.asarray(table), jnp.asarray(lengths))

@@ -89,7 +89,7 @@ class MultiModelEngine:
                  scheduler: Optional[SchedulerConfig] = None,
                  metrics: Optional[MetricsRegistry] = None,
                  tracer: Optional[Tracer] = None,
-                 attn_impl: str = "ref",
+                 attn_impl: Optional[str] = None,
                  debug_leak_check: bool = False):
         self.page_size = page_size
         self._num_pages = num_pages

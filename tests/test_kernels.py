@@ -12,6 +12,7 @@ import pytest
 from repro.core import HashedSpec, init
 from repro.kernels import ops, ref
 from repro.kernels import hashed_matmul as hk
+from repro.kernels import paged_attention as PA
 from repro.kernels.paged_attention import paged_decode_attention
 
 ELEMENT_CASES = [
@@ -284,6 +285,88 @@ def test_paged_attention_idle_rows_finite():
                                        interpret=True)):
         assert np.isfinite(np.asarray(out)).all()
         np.testing.assert_array_equal(np.asarray(out)[1], 0.0)
+
+
+# (page size, table slots, pages per block the kernel derives): blocks
+# of several pages (16 x 16 = 256 tokens, two a row), and of one page
+# (37 slots is prime: no block of 2-36 pages divides the table)
+LIVE_SHAPES = [(16, 32, 16), (8, 37, 1)]
+
+
+def _edge_lengths(ps, maxp, ppb):
+    """Idle, one token, one page, either side of the first block's end,
+    and the whole table."""
+    bk = ps * ppb
+    return [0, 1, ps, bk - 1, bk, bk + 1, maxp * ps]
+
+
+def _mk_live(ps, maxp, ppb, g, dtype, seed):
+    n_kv, d = 2, 16
+    assert PA.pages_per_block(maxp, ps, n_kv, d,
+                               jnp.dtype(dtype).itemsize) == ppb
+    return _mk_paged(seed, b=7, ps=ps, maxp=maxp, n_kv=n_kv, g=g, d=d,
+                     dtype=dtype, lengths=_edge_lengths(ps, maxp, ppb))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("g", [1, 2])
+@pytest.mark.parametrize("ps,maxp,ppb", LIVE_SHAPES)
+def test_paged_attention_live_blocks_vs_ref(ps, maxp, ppb, g, dtype):
+    """The block walk at the edges of a row's span: empty rows, one
+    token, a page, a block boundary +- 1 and the full table, through
+    scrambled pages, in blocks of one page and of several."""
+    q, pk, pv, table, lengths = _mk_live(ps, maxp, ppb, g, dtype, 31 + g)
+    got = paged_decode_attention(q, pk, pv, table, lengths, interpret=True)
+    want = ref.paged_attention_ref(q, pk, pv, table, lengths)
+    assert got.dtype == q.dtype
+    rtol, atol = _tol(dtype)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=rtol, atol=atol)
+    np.testing.assert_array_equal(np.asarray(got, np.float32)[0], 0.0)
+
+
+@pytest.mark.parametrize("window", [1, 3, 8])
+@pytest.mark.parametrize("ps,maxp,ppb", LIVE_SHAPES)
+def test_paged_attention_live_blocks_window(ps, maxp, ppb, window):
+    q, pk, pv, table, lengths = _mk_live(ps, maxp, ppb, 2, jnp.float32,
+                                         41 + window)
+    got = paged_decode_attention(q, pk, pv, table, lengths,
+                                 jnp.int32(window), interpret=True)
+    want = ref.paged_attention_ref(q, pk, pv, table, lengths, window)
+    rtol, atol = _tol(jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=rtol, atol=atol)
+
+
+@pytest.mark.parametrize("window", [0, 3])
+@pytest.mark.parametrize("ps,maxp,ppb", LIVE_SHAPES)
+def test_paged_attention_reads_nothing_past_the_length(ps, maxp, ppb,
+                                                       window):
+    """Every pool slot no row attends (past each row's length, outside
+    its window, the trash page, pages no row owns) holds NaN: the
+    kernel's output stays finite and equal to the oracle's on the
+    untouched pool, so nothing there was read into the result.  And
+    table slots past each row's length name pages beyond the pool: the
+    interpreter raises on a copy from one, so none was started."""
+    q, pk, pv, table, lengths = _mk_live(ps, maxp, ppb, 2, jnp.float32, 53)
+    attended = np.zeros(pk.shape[:2], bool)
+    tbl = np.asarray(table).copy()
+    for b, n in enumerate(np.asarray(lengths)):
+        lo = max(0, n - window) if window else 0
+        for t in range(lo, n):
+            attended[tbl[b, t // ps], t % ps] = True
+        tbl[b, -(-n // ps):] = pk.shape[0] + 7
+    nan = jnp.asarray(~attended)[:, :, None, None]
+    got = paged_decode_attention(q, jnp.where(nan, jnp.nan, pk),
+                                 jnp.where(nan, jnp.nan, pv),
+                                 jnp.asarray(tbl), lengths,
+                                 jnp.int32(window), interpret=True)
+    want = ref.paged_attention_ref(q, pk, pv, table, lengths, window)
+    assert np.isfinite(np.asarray(got)).all()
+    rtol, atol = _tol(jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=rtol, atol=atol)
 
 
 def _mk_shared_paged(seed, *, b, ps, maxp, n_kv, g, d, shared_pages,

@@ -358,6 +358,68 @@ def test_preemption_reproduces_greedy_tokens(tiny):
     eng.kv.leak_check()
 
 
+def test_attn_impl_default_is_the_platforms_choice(tiny, monkeypatch):
+    """Unset, the decode step attends through the paged kernel on a TPU
+    and through the gather oracle elsewhere; prefill keeps the oracle.
+    Explicit values pass through to both."""
+    from repro import runtime
+
+    m, params = tiny
+
+    def impls(**kw):
+        eng = Engine(m, params, max_concurrency=2, max_len=64, **kw)
+        return eng.decode_attn_impl, eng.prefill_attn_impl
+
+    assert impls() == ("ref", "ref")
+    for impl in ("ref", "pallas"):
+        assert impls(attn_impl=impl) == (impl, impl)
+    monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    assert impls() == ("pallas", "ref")
+    for impl in ("ref", "pallas"):
+        assert impls(attn_impl=impl) == (impl, impl)
+
+
+def test_step_attn_pages_are_the_decoding_rows_live_pages(tiny):
+    """``Step.attn_pages`` is the sum, over the rows a step decodes, of
+    ceil((cached + 1) / page): the pages the decode attention reads,
+    the current token's included.  The gauge is its share of the table."""
+    m, params = tiny
+    ps = 4
+    eng = Engine(m, params, max_concurrency=3, max_len=64, eos_id=-1,
+                 page_size=ps, prefill_chunk=8)
+    seen = []
+    decode = eng._decode_paged
+
+    def spy(p, t, pg, tb, ln):
+        seen.append(np.asarray(ln).copy())
+        return decode(p, t, pg, tb, ln)
+
+    eng._decode_paged = spy
+    rng = np.random.default_rng(5)
+    reqs = [Request(uid=i, prompt=_prompt(rng, 3, 20),
+                    max_new_tokens=int(rng.integers(3, 9)))
+            for i in range(5)]
+    for r in reqs:
+        assert eng.submit(r)
+    steps = []
+    while eng.pending():
+        n = len(seen)
+        eng.step()
+        if len(seen) > n:
+            # the step just run decoded, so it advanced some request
+            st = max((s for r in reqs for s in r.steps), key=lambda s: s.id)
+            ln = seen[-1]
+            live = ln[ln > 0]
+            assert len(live) == st.decode_rows
+            want = int(sum(-(-(int(x) + 1) // ps) for x in live))
+            assert st.attn_pages == want
+            share = eng.metrics.gauge("decode.attn_live_page_share").value
+            assert share == want / (3 * eng.kv.maxp)
+            steps.append(st)
+    assert len(steps) > 3 and any(s.attn_pages > s.decode_rows
+                                  for s in steps)
+
+
 def test_submit_rejects_never_fitting_request(tiny):
     """A request whose working set can never fit is refused at submit
     (otherwise it wedges the FIFO head forever)."""

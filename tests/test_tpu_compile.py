@@ -66,6 +66,30 @@ def test_paged_decode_compiles(one_chip):
         ((b, MAXP), jnp.int32), ((b,), jnp.int32))
 
 
+# v5e's default limit on a kernel's scoped VMEM
+SCOPED_VMEM = 16 * 1024 * 1024
+
+
+@pytest.mark.parametrize("hkv", [HKV, HKV // 4])
+def test_paged_decode_compiles_at_the_served_shape(one_chip, hkv):
+    """The benchmark's engine shape (32 rows, 128 table slots, 2,305
+    pages of 16), and its head shard at tensor-parallel 4: the kernel
+    walks blocks of 16 pages, whose buffers fit the scoped VMEM."""
+    b, maxp, pool = 32, 128, 2305
+    hq = hkv * (HQ // HKV)
+    ppb = PA.pages_per_block(maxp, PAGE, hkv, D, 2)
+    assert ppb == 16
+    assert PA.block_vmem_bytes(ppb, PAGE, hkv, D, 2) <= SCOPED_VMEM
+    _compile(
+        lambda q, pk, pv, tbl, ln, w: PA.paged_decode_attention(
+            q, pk, pv, tbl, ln, w, interpret=False),
+        one_chip,
+        ((b, hq, D), jnp.bfloat16),
+        ((pool, PAGE, hkv, D), jnp.bfloat16),
+        ((pool, PAGE, hkv, D), jnp.bfloat16),
+        ((b, maxp), jnp.int32), ((b,), jnp.int32), ((), jnp.int32))
+
+
 def test_flash_prefill_compiles(one_chip):
     b, s = 8, 16
     _compile(
